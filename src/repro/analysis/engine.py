@@ -60,6 +60,7 @@ class AnalysisConfig:
         "block_b": 64, "block_q": 512, "block_k": 512,
         "B": 1024, "T": 64, "H": 64, "D": 256, "G": 32, "K": 8192,
         "R": 1 << 20, "n": 64, "n_k": 64, "n_q": 64,
+        "W": 256,   # lane-line width of a packed row store: max(D, 128)
     })
 
 

@@ -78,6 +78,8 @@ def _eval_dim(node: ast.AST, dims: Dict[str, int], default: int) -> int:
             return left ** right
     if isinstance(node, ast.Call):
         chain = _attr_chain(node.func)
+        if chain and chain[-1] == "Squeezed":
+            return 1  # a squeezed block dim holds one element
         vals = [_eval_dim(a, dims, default) for a in node.args]
         if chain and vals:
             if chain[-1] == "max":

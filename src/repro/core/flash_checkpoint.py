@@ -344,7 +344,8 @@ class FlashCheckpoint:
             candidates = sorted(mem_steps | set(self._disk_steps()),
                                 reverse=True)
         if not candidates:
-            raise FileNotFoundError("no checkpoint available")
+            # torn dirs (no manifest) were skipped: none is a valid blob
+            raise FileNotFoundError("no valid checkpoint available")
         flat = None
         used_step = None
         for s in candidates:

@@ -16,3 +16,45 @@ from __future__ import annotations
 
 NEG_INF = -3.0e38       # max-combiner identity (≈ most negative finite f32)
 MASK_VALUE = -1e30      # attention score mask (softmax-safe)
+
+# ---------------------------------------------------------------------------
+# lane-packed row stores
+# ---------------------------------------------------------------------------
+LANES = 128             # TPU vector lanes: HBM/VMEM tiles are (8, 128) words
+
+
+def rows_per_line(D: int) -> int:
+    """Pool rows packed into one lane-dense line of ``max(D, LANES)`` words.
+
+    Mosaic addresses HBM in whole 128-lane lines, so a row DMA of a narrow
+    ``(R, D)`` pool (``D`` = 16, or 1 for a wide/linear part) is refused.
+    The row kernels therefore view the pool as lines: ``128 // D`` rows per
+    128-lane line when ``D`` divides 128, one row per line when ``D`` is a
+    multiple of 128.
+    """
+    if D % LANES == 0:
+        return 1
+    if LANES % D:
+        raise ValueError(f"row width {D} neither divides nor is a multiple "
+                         f"of {LANES} lanes")
+    return LANES // D
+
+
+def lane_pack(pool):
+    """(R, D) row store -> (ceil(R / P), P * D) lines, P = ``rows_per_line``.
+
+    Row ``v`` sits in line ``v // P`` at lanes ``[(v % P) * D, +D)``; the
+    tail line is zero-padded.
+    """
+    import jax.numpy as jnp
+    R, D = pool.shape
+    P = rows_per_line(D)
+    n_lines = -(-R // P)
+    if n_lines * P != R:
+        pool = jnp.pad(pool, ((0, n_lines * P - R), (0, 0)))
+    return pool.reshape(n_lines, P * D)
+
+
+def lane_unpack(lines, R: int, D: int):
+    """Inverse of ``lane_pack``: (n_lines, P * D) lines -> (R, D) rows."""
+    return lines.reshape(-1, D)[:R]

@@ -44,17 +44,24 @@ Hot-row cache (skew-aware placement contract)
     with a fresh ``table_hot`` — any plan computes identical numerics.
 
 Forward (Pallas path, double-buffered)
-    The grid is ``(ceil(B/block_b), T)``; the batch is padded on the host to
-    a whole number of blocks so no grid step ever sees unspecified block
-    padding. Each step receives its ``(block_b, 1, H)`` slice of the
-    *encoded* index tensor as a tiny SMEM block (hot lookups are encoded as
-    ``-(cache_slot+1)``, cold ones as the global pool row). Row staging is
-    double-buffered across grid steps — two VMEM staging buffers and two DMA
-    semaphores: while step ``i`` drains its buffer and reduces it into a
-    ``(block_b, 1, D)`` output block, step ``i``'s body has already issued
-    the copies for step ``i+1`` into the other buffer (the next step's index
-    slice is delivered through a second, look-ahead SMEM block), so HBM copy
-    latency overlaps the reduction instead of serializing with it.
+    The grid is ``(T, ceil(B/block_b))`` and the kernel works table-major:
+    the batch is padded to a whole number of blocks, the encoded index
+    tensor is laid out ``(T, B_pad, H)`` and flattened, and the output is
+    ``(T, B_pad, W)`` (sliced and transposed back to ``(B, T, D)`` by XLA).
+    The pool is read as lane-packed lines of ``W = max(D, 128)`` words
+    (``common.lane_pack``), because Mosaic, the TPU kernel compiler, moves
+    HBM data in whole 128-lane lines; a lane rotate brings each staged row
+    to lane 0. So every block's two minor dims are ``(block_b, W)`` or a
+    full index row, as Mosaic tiles arrays. Each step receives its
+    ``block_b * H`` slice of the *encoded* indices as a 1-D SMEM block (hot
+    lookups are encoded as ``-(cache_slot+1)``, cold ones as the global pool
+    row). Row staging is double-buffered across grid steps — two
+    ``(H, block_b, W)`` VMEM staging buffers and two DMA semaphores: while
+    step ``i`` drains its buffer and reduces it into its ``(block_b, W)``
+    output tile, step ``i``'s body has already issued the copies for step
+    ``i+1`` into the other buffer (the next step's index slice is delivered
+    through a second, look-ahead SMEM block), so HBM copy latency overlaps
+    the reduction instead of serializing with it.
 
 Forward (XLA fallback)
     One ``jnp.take`` over the pool + one reduction over the hot axis — no
@@ -81,6 +88,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from repro.kernels.common import lane_pack, rows_per_line
 
 COMBINERS = ("sum", "mean", "max")
 
@@ -195,60 +205,63 @@ def encode_hot_indices(idx, offsets: Sequence[int],
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: (ceil(B/block_b), T) grid, double-buffered row staging
+# Pallas kernel: (T, ceil(B/block_b)) grid, double-buffered row staging
 # ---------------------------------------------------------------------------
-def _fill_stage(stage_ref, sem, blk_ref, pool_ref, cache_ref, *,
-                R: int, K: int, H: int, block_b: int):
-    """Stage one block's rows: hot slots from VMEM cache, cold rows via DMA."""
+def _row_copy(lines_ref, stage_ref, sem, v, r: int, j: int, *, L: int, P: int):
+    """The DMA of the lane line holding pool row ``v`` into slot ``(j, r)``."""
+    return pltpu.make_async_copy(
+        lines_ref.at[pl.ds(jnp.clip(v // P, 0, L - 1), 1), :],
+        stage_ref.at[j].at[pl.ds(r, 1), :],
+        sem,
+    )
+
+
+def _fill_stage(stage_ref, sem, blk_ref, lines_ref, cache_ref, *,
+                L: int, P: int, K: int, H: int, block_b: int):
+    """Stage one block's rows: hot slots from VMEM cache, cold lines via DMA."""
     for r in range(block_b):
         for j in range(H):
-            v = blk_ref[r, 0, j]
+            v = blk_ref[0, r * H + j]
             if cache_ref is None:
-                pltpu.make_async_copy(
-                    pool_ref.at[pl.ds(jnp.clip(v, 0, R - 1), 1), :],
-                    stage_ref.at[r].at[pl.ds(j, 1), :],
-                    sem,
-                ).start()
+                _row_copy(lines_ref, stage_ref, sem, v, r, j, L=L, P=P).start()
             else:
                 @pl.when(v >= 0)
                 def start_cold(v=v, r=r, j=j):
-                    pltpu.make_async_copy(
-                        pool_ref.at[pl.ds(jnp.clip(v, 0, R - 1), 1), :],
-                        stage_ref.at[r].at[pl.ds(j, 1), :],
-                        sem,
-                    ).start()
+                    _row_copy(lines_ref, stage_ref, sem, v, r, j,
+                              L=L, P=P).start()
 
                 @pl.when(v < 0)
                 def copy_hot(v=v, r=r, j=j):
                     slot = jnp.clip(-v - 1, 0, K - 1)
-                    row = pl.load(cache_ref, (pl.ds(slot, 1), slice(None)))
-                    pl.store(stage_ref,
-                             (pl.ds(r, 1), pl.ds(j, 1), slice(None)),
-                             row[None])
+                    stage_ref[j, pl.ds(r, 1), :] = cache_ref[pl.ds(slot, 1), :]
 
 
-def _drain_stage(stage_ref, sem, blk_ref, pool_ref, cached: bool, *,
-                 R: int, H: int, block_b: int):
-    """Wait for exactly the DMAs `_fill_stage` issued for this block."""
+def _drain_stage(stage_ref, sem, blk_ref, lines_ref, cached: bool, *,
+                 L: int, P: int, D: int, H: int, block_b: int):
+    """Wait for exactly the DMAs `_fill_stage` issued for this block, then
+    rotate every staged line so its row starts at lane 0 (cold row ``v``
+    sits at lane ``(v % P) * D`` of its line; cached rows already at 0)."""
     for r in range(block_b):
         for j in range(H):
-            v = blk_ref[r, 0, j]
-            cp = pltpu.make_async_copy(
-                pool_ref.at[pl.ds(jnp.clip(v, 0, R - 1), 1), :],
-                stage_ref.at[r].at[pl.ds(j, 1), :],
-                sem,
-            )
+            v = blk_ref[0, r * H + j]
+            cp = _row_copy(lines_ref, stage_ref, sem, v, r, j, L=L, P=P)
             if cached:
                 @pl.when(v >= 0)
                 def wait_cold(cp=cp):
                     cp.wait()
             else:
                 cp.wait()
+            if P > 1:
+                width = stage_ref.shape[-1]
+                lane = jnp.where(v >= 0, jax.lax.rem(v, P) * D, 0)
+                stage_ref[j, pl.ds(r, 1), :] = pltpu.roll(
+                    stage_ref[j, pl.ds(r, 1), :],
+                    jax.lax.rem(width - lane, width), 1)
 
 
-def _fused_kernel(idx_ref, nxt_ref, pool_ref, *refs,
-                  R: int, K: int, H: int, block_b: int, combiner: str,
-                  weighted: bool, cached: bool):
+def _fused_kernel(idx_ref, nxt_ref, lines_ref, *refs,
+                  L: int, P: int, D: int, K: int, H: int, block_b: int,
+                  combiner: str, weighted: bool, cached: bool):
     # refs = (cache_ref?, w_ref?, out_ref, stage_a, stage_b, sem)
     i = 0
     cache_ref = refs[i] if cached else None
@@ -260,102 +273,128 @@ def _fused_kernel(idx_ref, nxt_ref, pool_ref, *refs,
     step = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
     nsteps = pl.num_programs(0) * pl.num_programs(1)
     parity = jax.lax.rem(step, 2)
-    fill_kw = dict(R=R, K=K, H=H, block_b=block_b)
-    drain_kw = dict(R=R, H=H, block_b=block_b)
+    fill_kw = dict(L=L, P=P, K=K, H=H, block_b=block_b)
+    drain_kw = dict(L=L, P=P, D=D, H=H, block_b=block_b)
 
     # warm-up: the very first step stages its own rows
     @pl.when(step == 0)
     def warmup():
-        _fill_stage(stage_a, sem.at[0], idx_ref, pool_ref, cache_ref, **fill_kw)
+        _fill_stage(stage_a, sem.at[0], idx_ref, lines_ref, cache_ref,
+                    **fill_kw)
 
     # prefetch step i+1's rows into the other buffer while this step reduces
     @pl.when((step + 1 < nsteps) & (parity == 0))
     def prefetch_into_b():
-        _fill_stage(stage_b, sem.at[1], nxt_ref, pool_ref, cache_ref, **fill_kw)
+        _fill_stage(stage_b, sem.at[1], nxt_ref, lines_ref, cache_ref,
+                    **fill_kw)
 
     @pl.when((step + 1 < nsteps) & (parity == 1))
     def prefetch_into_a():
-        _fill_stage(stage_a, sem.at[0], nxt_ref, pool_ref, cache_ref, **fill_kw)
+        _fill_stage(stage_a, sem.at[0], nxt_ref, lines_ref, cache_ref,
+                    **fill_kw)
 
     def reduce_from(stage_ref):
-        rows = stage_ref[...].astype(jnp.float32)      # (block_b, H, D)
+        # lanes [0, D) hold the looked-up rows; the rest are neighbours from
+        # the same line, reduced alongside and sliced off by the caller
+        rows = stage_ref[...].astype(jnp.float32)       # (H, block_b, W)
         if weighted:
-            rows = rows * w_ref[:, 0, :][..., None]    # (block_b, H, 1)
+            # a zero weight contributes exactly 0; the select also keeps
+            # each product rounded on its own, as the XLA path's reduce sees
+            # it (no multiply-add contraction across the sum)
+            w = jnp.broadcast_to(w_ref[...], rows.shape)  # from (H, block_b, 1)
+            rows = jnp.where(w == 0, 0.0, rows * w)
         if combiner == "max":
-            res = jnp.max(rows, axis=1)
+            res = jnp.max(rows, axis=0)
         else:
-            res = jnp.sum(rows, axis=1)
+            res = jnp.sum(rows, axis=0)
             if combiner == "mean":
                 res = res / H
-        out_ref[...] = res[:, None, :].astype(out_ref.dtype)
+        out_ref[...] = res.astype(out_ref.dtype)
 
     @pl.when(parity == 0)
     def consume_a():
-        _drain_stage(stage_a, sem.at[0], idx_ref, pool_ref, cached, **drain_kw)
+        _drain_stage(stage_a, sem.at[0], idx_ref, lines_ref, cached,
+                     **drain_kw)
         reduce_from(stage_a)
 
     @pl.when(parity == 1)
     def consume_b():
-        _drain_stage(stage_b, sem.at[1], idx_ref, pool_ref, cached, **drain_kw)
+        _drain_stage(stage_b, sem.at[1], idx_ref, lines_ref, cached,
+                     **drain_kw)
         reduce_from(stage_b)
 
 
 def _pallas_forward(pool, enc_idx, weights, cache, *, B, T, H, combiner,
                     block_b, interpret):
     R, D = pool.shape
+    P = rows_per_line(D)
+    lines = lane_pack(pool)                                     # (L, W)
+    L, W = lines.shape
     K = 0 if cache is None else cache.shape[0]
+    if K > 0:
+        # cached rows sit at lanes [0, D) of their own line
+        cache = jnp.pad(cache, ((0, 0), (0, W - D)))
     nb = pl.cdiv(B, block_b)
-    nsteps = nb * T
+    nsteps = T * nb
     # pad the batch to whole blocks: encoded index 0 is a harmless cold DMA
     # of pool row 0, so no grid step ever sees unspecified block padding
     B_pad = nb * block_b
-    enc_idx = enc_idx.reshape(B, T, H)
-    if B_pad != B:
-        enc_idx = jnp.pad(enc_idx, ((0, B_pad - B), (0, 0), (0, 0)))
-        if weights is not None:
-            weights = jnp.pad(weights.reshape(B, T, H),
-                              ((0, B_pad - B), (0, 0), (0, 0)))
+    # table-major layout: grid step (t, bb) owns one contiguous index row
+    # and the (block_b, W) output tile of table t, batch block bb — blocks
+    # whose two minor dims are (8k or full, full), as Mosaic tiles them
+    enc_idx = jnp.pad(enc_idx.reshape(B, T, H), ((0, B_pad - B), (0, 0), (0, 0)))
+    enc_idx = enc_idx.transpose(1, 0, 2).reshape(nsteps, 1, block_b * H)
+    if weights is not None:
+        # (T, H, B_pad, 1): each lookup weight broadcasts along lanes
+        weights = jnp.pad(weights.reshape(B, T, H),
+                          ((0, B_pad - B), (0, 0), (0, 0)))
+        weights = weights.transpose(1, 2, 0)[..., None]
 
-    def nxt_map(bb, t):
-        # look-ahead SMEM block: the (bb, t) step receives step bb*T+t+1's
-        # index slice so it can prefetch into the idle staging buffer
-        lin = jnp.minimum(bb * T + t + 1, nsteps - 1)
-        return (lin // T, jax.lax.rem(lin, T), 0)
+    def nxt_map(t, bb):
+        # look-ahead SMEM block: step (t, bb) receives the next step's index
+        # row so it can prefetch into the idle staging buffer
+        return (jnp.minimum(t * nb + bb + 1, nsteps - 1), 0, 0)
 
     kernel = functools.partial(
-        _fused_kernel, R=R, K=max(K, 1), H=H, block_b=block_b,
+        _fused_kernel, L=L, P=P, D=D, K=max(K, 1), H=H, block_b=block_b,
         combiner=combiner, weighted=weights is not None, cached=K > 0)
     in_specs = [
-        # per-step (block_b, 1, H) encoded-index slices staged to SMEM — the
+        # per-step (1, block_b*H) encoded-index rows staged to SMEM — the
         # full index tensor never has to fit on-chip
-        pl.BlockSpec((block_b, 1, H), lambda bb, t: (bb, t, 0),
+        pl.BlockSpec((pl.Squeezed(), 1, block_b * H),
+                     lambda t, bb: (t * nb + bb, 0, 0),
                      memory_space=pltpu.SMEM),
-        pl.BlockSpec((block_b, 1, H), nxt_map, memory_space=pltpu.SMEM),
-        pl.BlockSpec(memory_space=pltpu.ANY),        # pool (manual DMA)
+        pl.BlockSpec((pl.Squeezed(), 1, block_b * H), nxt_map,
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pl.ANY),           # pool lines (manual DMA)
     ]
-    args = [enc_idx, enc_idx, pool]
+    args = [enc_idx, enc_idx, lines]
     if K > 0:
         # constant index map -> fetched once, VMEM-resident across the grid
-        in_specs.append(pl.BlockSpec((K, D), lambda bb, t: (0, 0)))
+        in_specs.append(pl.BlockSpec((K, W), lambda t, bb: (0, 0)))
         args.append(cache)
     if weights is not None:
-        in_specs.append(
-            pl.BlockSpec((block_b, 1, H), lambda bb, t: (bb, t, 0)))
-        args.append(weights.reshape(B_pad, T, H))
+        in_specs.append(pl.BlockSpec((pl.Squeezed(), H, block_b, 1),
+                                     lambda t, bb: (t, 0, bb, 0)))
+        args.append(weights)
     out = pl.pallas_call(
         kernel,
-        grid=(nb, T),
+        grid=(T, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_b, 1, D), lambda bb, t: (bb, t, 0)),
+        out_specs=pl.BlockSpec((pl.Squeezed(), block_b, W),
+                               lambda t, bb: (t, bb, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_b, H, D), pool.dtype),
-            pltpu.VMEM((block_b, H, D), pool.dtype),
+            pltpu.VMEM((H, block_b, W), pool.dtype),
+            pltpu.VMEM((H, block_b, W), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        out_shape=jax.ShapeDtypeStruct((B_pad, T, D), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, B_pad, W), pool.dtype),
+        # the staging buffers carry state from one grid step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*args)
-    return out[:B] if B_pad != B else out
+    return out[:, :B, :D].transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -497,7 +536,7 @@ def sparse_row_grads(pool: jnp.ndarray, indices: jnp.ndarray, g: jnp.ndarray,
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _fused(pool, flat_idx, weights, meta):
-    combiner, B, T, H, method, block_b, hot, layout = meta
+    combiner, B, T, H, method, block_b, hot, layout, mesh = meta
     if method in ("pallas", "interpret"):
         if hot is not None:
             offsets, table_hot = hot
@@ -528,9 +567,15 @@ def _fused(pool, flat_idx, weights, meta):
             enc = flat_idx.reshape(B, T, H)
             if layout is not None:
                 enc = translate_rows(enc, layout)
-        return _pallas_forward(pool, enc, weights, cache, B=B, T=T, H=H,
-                               combiner=combiner, block_b=block_b,
-                               interpret=(method == "interpret"))
+        fwd = functools.partial(_pallas_forward, B=B, T=T, H=H,
+                                combiner=combiner, block_b=block_b,
+                                interpret=(method == "interpret"))
+        if mesh is not None and method == "pallas":
+            # GSPMD cannot partition a Mosaic kernel: every device runs it
+            # on the whole pool, which is all-gathered (replicated) around it
+            fwd = jax.shard_map(fwd, mesh=mesh, in_specs=P(), out_specs=P(),
+                                check_vma=False)
+        return fwd(pool, enc, weights, cache)
     # XLA path: under frequency-packed placement the hot prefixes are already
     # contiguous in the pool and stay hardware-cache-resident; a separate
     # cache gather would only add traffic, so the plain fused take IS the
@@ -545,7 +590,7 @@ def _fused_fwd(pool, flat_idx, weights, meta):
 
 
 def _fused_bwd(meta, res, g):
-    combiner, B, T, H, method, block_b, hot, layout = meta
+    combiner, B, T, H, method, block_b, hot, layout, _ = meta
     pool, flat_idx, weights = res
     R, D = pool.shape
     # gradients deposit into the physical store's row space: flat rows when
@@ -580,7 +625,7 @@ def fused_embedding_bag(pool: jnp.ndarray, indices: jnp.ndarray,
                         combiner: str = "sum", method: str = "xla",
                         block_b: int = 8,
                         table_hot: Optional[Sequence[int]] = None,
-                        layout=None, plan=None) -> jnp.ndarray:
+                        layout=None, plan=None, mesh=None) -> jnp.ndarray:
     """Pool per-table embedding bags for all tables in one fused call.
 
     Args:
@@ -614,6 +659,9 @@ def fused_embedding_bag(pool: jnp.ndarray, indices: jnp.ndarray,
                  ``offsets``/``combiner``/``block_b``/``table_hot``/``layout``
                  in one hashable value (overrides the loose kwargs; the
                  preferred form — see ``kernels/ops.py``).
+      mesh:      the device mesh the calling step is partitioned over, if
+                 any; the Pallas kernel then runs replicated on every device
+                 (a Mosaic kernel is not partitioned by GSPMD).
 
     Returns (B, T, D); gradients flow to ``pool`` (sparse scatter-add of
     the deduped row cotangents, into padded rows under ``layout``) and
@@ -645,5 +693,6 @@ def fused_embedding_bag(pool: jnp.ndarray, indices: jnp.ndarray,
             hot = (offs, table_hot)
     flat_idx = idx.reshape(-1)
     w = None if weights is None else weights.astype(jnp.float32)
-    meta = (combiner, B, T, H, method, max(1, min(block_b, B)), hot, layout)
+    meta = (combiner, B, T, H, method, max(1, min(block_b, B)), hot, layout,
+            mesh)
     return _fused(pool, flat_idx, w, meta)

@@ -1,12 +1,18 @@
 """Public jit-friendly kernel wrappers with implementation dispatch.
 
 impl:
-  "xla"       — scalable pure-JAX (chunked flash) path; default on CPU and for
-                 the multi-pod dry-run (memory-safe lowering, same math).
-  "pallas"    — Pallas TPU kernels (compiled for TPU targets).
-  "interpret" — Pallas kernels in interpret mode (CPU correctness testing).
+  "pallas"    — Pallas TPU kernels, compiled by Mosaic. The default on a TPU
+                backend.
+  "xla"       — pure-JAX path (chunked flash attention, one fused gather per
+                embedding call). The default on every other backend, and the
+                plain float32 reference the kernels are tested against.
+  "interpret" — Pallas kernels in interpret mode: the kernel bodies' numerics
+                on CPU. Never a default; tests ask for it per call with
+                ``impl=`` or pin it with ``set_default_impl``.
 
-Set globally via ``set_default_impl`` or per-call with ``impl=``.
+Calls without ``impl=`` resolve it when they are traced, from
+``jax.default_backend()``. Asking for "pallas" on a backend that is not a
+TPU raises instead of quietly running the reference.
 
 Embedding calls are planned by a single ``repro.sharding.policy
 .EmbeddingPlan`` value (``plan=``): the frozen, hashable bundle of the
@@ -17,12 +23,16 @@ once per process.
 """
 from __future__ import annotations
 
-import os
 import warnings
+from typing import Optional
+
+import jax
 
 from repro.models import attention as _xla_attn
 
-_DEFAULT_IMPL = os.environ.get("REPRO_KERNEL_IMPL", "xla")
+IMPLS = ("xla", "pallas", "interpret")
+
+_PINNED_IMPL: Optional[str] = None      # set_default_impl (tests only)
 
 _LEGACY_KWARGS_WARNED = False
 
@@ -53,19 +63,41 @@ def _shim_plan(offsets, combiner, block_b, table_hot, layout):
         layout=layout)
 
 
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "interpret"), impl
-    _DEFAULT_IMPL = impl
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def set_default_impl(impl: Optional[str]) -> None:
+    """Pin the implementation of calls made without ``impl=`` (tests);
+    ``None`` restores the platform default."""
+    global _PINNED_IMPL
+    assert impl is None or impl in IMPLS, impl
+    _PINNED_IMPL = impl
 
 
 def get_default_impl() -> str:
-    return _DEFAULT_IMPL
+    """The implementation a call without ``impl=`` runs: the pinned one, else
+    "pallas" on a TPU backend and "xla" on any other."""
+    if _PINNED_IMPL is not None:
+        return _PINNED_IMPL
+    return "pallas" if _backend() == "tpu" else "xla"
+
+
+def resolve_impl(impl: Optional[str]) -> str:
+    """``impl`` or the default; "pallas" only where a TPU compiles it."""
+    impl = impl or get_default_impl()
+    if impl not in IMPLS:
+        raise ValueError(f"unknown kernel impl {impl!r}; one of {IMPLS}")
+    if impl == "pallas" and _backend() != "tpu":
+        raise RuntimeError(
+            f"impl='pallas' needs a TPU backend, not {_backend()!r}; use "
+            "'interpret' to run the kernel bodies on this backend")
+    return impl
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=0.0,
                     q_chunk=1024, k_chunk=1024, q_offset=0, impl=None):
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         from repro.kernels import flash_attention as fa
         return fa.flash_attention(
@@ -78,7 +110,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=0.0,
 
 def decode_attention(q, k_cache, v_cache, cache_pos, pos, *, window=None,
                      softcap=0.0, impl=None):
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if impl in ("pallas", "interpret"):
         from repro.kernels import decode_attention as da
         return da.decode_attention(
@@ -111,7 +143,7 @@ def fused_embedding_bag(pool, indices, weights=None, *, plan=None, impl=None,
     The loose ``offsets``/``combiner``/``block_b``/``table_hot``/``layout``
     kwargs are deprecated (warn-once shim building a plan internally).
     """
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if plan is None:
         plan = _shim_plan(offsets, combiner, block_b, table_hot, layout)
     else:
@@ -119,8 +151,9 @@ def fused_embedding_bag(pool, indices, weights=None, *, plan=None, impl=None,
                 and table_hot is None and layout is None), \
             "pass the static knobs inside plan=, not alongside it"
     from repro.kernels import fused_embedding as fe
+    from repro.sharding.policy import current_policy
     return fe.fused_embedding_bag(pool, indices, weights, method=impl,
-                                  plan=plan)
+                                  plan=plan, mesh=current_policy().mesh)
 
 
 def sparse_row_grads(pool, indices, g, weights=None, *, plan):
@@ -148,7 +181,7 @@ def fused_row_update(params, rows, vals, *state, kind, impl=None, block=8,
     gather/scatter fallback ("xla"); hyperparameters ride in ``hyper``
     (see ``repro.kernels.fused_update``).
     """
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     from repro.kernels import fused_update as fu
     if kind == "adagrad":
         (acc,) = state

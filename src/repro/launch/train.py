@@ -1,8 +1,8 @@
 """Training launcher: train any --arch with the full DLRover-RM substrate.
 
-On this CPU host it runs a reduced config end-to-end (real training); with
---mesh it builds the logical-axis policy and shardings exactly as the
-production launch would (the multi-pod path is exercised by dryrun.py).
+It runs a reduced config by default and the full published config with
+``--full``; on a TPU backend the fused embedding engine runs its Pallas
+kernels, on any other backend the XLA path (``repro.kernels.ops``).
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
         --steps 100 --batch 8 --seq 64 [--reduced/--full] [--ckpt-dir DIR]
@@ -39,12 +39,15 @@ from repro.core.flash_checkpoint import FlashCheckpoint
 from repro.core.sharding_service import HotTableTracker, ShardingService
 from repro.data.pipeline import ShardDataLoader
 from repro.data.synthetic import criteo_batch, lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.sharding.policy import padded_layout_for_ranges, uniform_vocab_ranges
 from repro.train import optim, replan, trainer
 
 
-def main() -> None:
+def main(argv=None):
+    """Parse ``argv`` (default: the command line) and train; returns what
+    the DLRM trainer returns (None for the other entry paths)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--steps", type=int, default=100)
@@ -56,7 +59,8 @@ def main() -> None:
                     choices=["adam", "adamw", "adagrad", "sgd"],
                     help="default: adamw for LMs, adagrad for DLRMs")
     ap.add_argument("--full", action="store_true",
-                    help="use the full published config (needs real HW)")
+                    help="use the full published config (sized for one TPU "
+                         "chip; the default is a reduced config)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--grad-compress", action="store_true")
@@ -114,7 +118,8 @@ def main() -> None:
                     help="capped restart budget of the supervisor")
     ap.add_argument("--event-log", default=None, metavar="PATH",
                     help="write the supervisor's structured event log (JSONL)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.arch in DLRMS:
         if args.chaos_proc is not None:
@@ -122,8 +127,8 @@ def main() -> None:
         elif args.chaos or args.supervise:
             train_dlrm_supervised(args)
         else:
-            train_dlrm(args)
-        return
+            return train_dlrm(args)
+        return None
     if args.batch is None:
         args.batch = 8
 
@@ -178,13 +183,18 @@ def main() -> None:
         print(f"checkpointed at step {n} -> {args.ckpt_dir}")
 
 
-def train_dlrm(args) -> None:
+def train_dlrm(args) -> dict:
     """DLRM training with the live embedding re-planning loop wired in.
 
     Checkpoints are layout-stamped (``replan.save_with_layout``): each blob
     carries the composed raw-id → layout map and the active cache plan, so
     ``--resume`` in a fresh process keeps training correctly no matter how
-    many re-plans the previous run applied.
+    many re-plans the previous run applied. A resumed run reads the sample
+    stream from the restored global step on, and trains ``--steps`` more.
+
+    Returns ``{"start_step", "losses" {global step: loss}, "replans",
+    "first_step_s"}``; ``first_step_s`` is the wall time to the first
+    step's loss, compile included.
     """
     from repro.configs.dlrm_models import reduced_dlrm
 
@@ -206,6 +216,7 @@ def train_dlrm(args) -> None:
     vocab_ranges = None                          # None = uniform striping
     layout = None                                # None = flat pooled store
     state = None
+    step0 = 0
     if args.resume and ckpt.latest_step() is not None:
         state, step0, remapper, table_hot, vocab_ranges, layout = \
             replan.restore_with_layout(cfg, opt, ckpt)
@@ -249,18 +260,25 @@ def train_dlrm(args) -> None:
 
     total = args.steps * cfg.batch_size
     svc = ShardingService(total, shard_size=max(cfg.batch_size * 8, 64))
+    first = step0 * cfg.batch_size               # sample stream resumes here
     loader = ShardDataLoader(
-        svc, "worker0", lambda idx: criteo_batch(cfg, 11, idx),
+        svc, "worker0", lambda idx: criteo_batch(cfg, 11, first + idx),
         batch_size=cfg.batch_size)
 
     t0 = time.time()
     n = 0
+    losses = {}                                  # device scalars, read at end
+    first_step_s = None
     for raw in loader:
         batch = remapper.remap_batch(raw)
         tracker.observe(batch["sparse"])        # worker-side heartbeat payload
         state, m = step_fn(state, {k: jnp.asarray(v) for k, v in batch.items()})
+        losses[step0 + n] = m["loss"]
         n += 1
         replanned = False
+        if n == 1:
+            m["loss"].block_until_ready()
+            first_step_s = time.time() - t0
         if n % 20 == 0 or n == 1:
             print(f"step {n:5d} loss={float(m['loss']):.4f} "
                   f"imbalance={tracker.imbalance():.3f} "
@@ -306,6 +324,9 @@ def train_dlrm(args) -> None:
                                 layout=layout)
         ckpt.wait()
         print(f"checkpointed at step {n} -> {args.ckpt_dir}")
+    return {"start_step": step0,
+            "losses": {k: float(v) for k, v in losses.items()},
+            "replans": tracker.n_replans, "first_step_s": first_step_s}
 
 
 def train_dlrm_supervised(args) -> None:
@@ -390,7 +411,8 @@ def train_dlrm_chaos_proc(args) -> None:
         arch=args.arch, steps=args.steps, ckpt_every=args.ckpt_every,
         n_ps=args.n_ps, padded=args.padded_shards,
         chaos_proc=args.chaos_proc,
-        opt_name=args.optimizer or "adagrad", lr=args.lr)
+        opt_name=args.optimizer or "adagrad", lr=args.lr, full=args.full,
+        zipf_alpha=args.zipf_alpha, hot_rows=args.hot_rows)
     master = JobMaster([spec], JobMasterConfig(
         heartbeat_deadline_s=args.heartbeat_deadline,
         max_reexecs=args.max_restarts, seed=args.chaos_seed))

@@ -67,6 +67,36 @@ def dlrm_state_shardings(cfg: DLRMConfig, opt_name: str,
     return logical_spec(None, specs, policy)
 
 
+def make_dlrm_mesh_step(cfg: DLRMConfig, optimizer: Optimizer, opt_name: str,
+                        mesh, plan):
+    """The DLRM train step jitted over ``mesh`` under the DLRM policy.
+
+    The state is sharded by ``dlrm_state_shardings`` (pooled rows — the
+    padded ``(n_ps, max_range, D)`` store under ``plan.layout`` — over the
+    "model"/PS axis, dense params replicated) and the batch over "data";
+    the policy is active while the step traces, so the model's
+    ``constrain`` calls see the mesh.
+
+    Returns ``(step_fn, state_shardings, batch_sharding)``; place the state
+    with ``jax.device_put(state, state_shardings)``.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.sharding.policy import use_policy
+    policy = make_dlrm_policy(mesh)
+    shardings = dlrm_state_shardings(cfg, opt_name, policy, layout=plan.layout)
+    batch_sharding = NamedSharding(mesh, PartitionSpec(policy.rules["batch"]))
+    step = trainer_mod.make_dlrm_train_step(cfg, optimizer, plan=plan)
+
+    def traced(state, batch):
+        with use_policy(policy):
+            return step(state, batch)
+
+    step_fn = jax.jit(traced, in_shardings=(shardings, batch_sharding),
+                      out_shardings=(shardings, None))
+    return step_fn, shardings, batch_sharding
+
+
 def resume_dlrm_on_mesh(cfg: DLRMConfig, optimizer: Optimizer, opt_name: str,
                         ckpt: FlashCheckpoint, mesh, *,
                         decision=None, step: Optional[int] = None,

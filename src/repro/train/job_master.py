@@ -116,6 +116,9 @@ class WorkerSpec:
     lr: float = 0.05
     init_seed: int = 0
     data_seed: int = 11
+    full: bool = False               # full published config, not reduced
+    zipf_alpha: float = 0.0          # sparse-id skew of the sample stream
+    hot_rows: int = 0                # hot-row cache budget (0 = off)
     extra_args: Tuple[str, ...] = ()
 
     @property
@@ -139,12 +142,16 @@ class WorkerSpec:
                "--optimizer", self.opt_name, "--lr", str(self.lr),
                "--init-seed", str(self.init_seed),
                "--data-seed", str(self.data_seed),
+               "--zipf-alpha", str(self.zipf_alpha),
+               "--hot-rows", str(self.hot_rows),
                "--heartbeat", self.heartbeat_path,
                "--losses", self.losses_path,
                "--fault-log", self.faults_path,
                "--incarnation", str(incarnation)]
         if self.padded:
             cmd.append("--padded")
+        if self.full:
+            cmd.append("--full")
         if self.chaos_proc:
             cmd += ["--chaos-proc", self.chaos_proc]
         cmd += list(self.extra_args)

@@ -1,6 +1,7 @@
 """Worker-process entrypoint spawned (and re-exec'd) by the job master.
 
-One incarnation of one worker: build the reduced DLRM job, resume from the
+One incarnation of one worker: build the DLRM job (reduced, or the full
+published config with ``--full``), resume from the
 newest valid layout-stamped checkpoint in ``--ckpt-dir`` (fresh init when
 none), then train to ``--steps`` global steps, publishing a heartbeat file
 after every step and appending each step's loss to a shared JSONL log.
@@ -46,6 +47,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--lr", type=float, default=0.05)
     ap.add_argument("--init-seed", type=int, default=0)
     ap.add_argument("--data-seed", type=int, default=11)
+    ap.add_argument("--full", action="store_true",
+                    help="the full published config instead of the reduced one")
+    ap.add_argument("--zipf-alpha", type=float, default=0.0,
+                    help="power-law skew of the sparse-feature stream")
+    ap.add_argument("--hot-rows", type=int, default=0,
+                    help="hot-row cache budget in pooled rows (0 = off)")
     ap.add_argument("--heartbeat", required=True,
                     help="heartbeat JSON path (atomically replaced per step)")
     ap.add_argument("--losses", required=True,
@@ -71,13 +78,21 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     beat(-1, "boot")
 
+    import dataclasses
+
     from repro.configs.dlrm_models import reduced_dlrm
     from repro.configs.registry import get_dlrm
     from repro.core.faults import ProcessFaultInjector, parse_chaos_spec
     from repro.core.flash_checkpoint import FlashCheckpoint
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train.supervisor import DLRMJob
 
-    cfg = reduced_dlrm(get_dlrm(args.arch))
+    enable_compile_cache()       # a re-exec'd incarnation reuses the compile
+    cfg = get_dlrm(args.arch)
+    if not args.full:
+        cfg = reduced_dlrm(cfg)
+    cfg = dataclasses.replace(cfg, zipf_alpha=args.zipf_alpha,
+                              hot_rows_k=args.hot_rows)
     injector = ProcessFaultInjector(
         parse_chaos_spec(args.chaos_proc), incarnation=args.incarnation,
         log_path=args.fault_log)

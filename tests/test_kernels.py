@@ -5,6 +5,7 @@ Sweeps shapes/dtypes with hypothesis; every kernel must match ref.py.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
@@ -184,3 +185,35 @@ def test_decode_attention_ring_wrap():
                            interpret=True)
     expect = ref.decode_attention_ref(q, kc, vc, cache_pos, pos, window=12)
     np.testing.assert_allclose(out, expect, atol=3e-5, rtol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# implementation selection
+# ---------------------------------------------------------------------------
+def test_kernel_impl_follows_backend(monkeypatch):
+    """No impl= on CPU runs the XLA path; on a TPU backend, the kernels."""
+    assert jax.default_backend() == "cpu"
+    assert ops.get_default_impl() == "xla"
+    assert ops.resolve_impl(None) == "xla"
+    assert ops.resolve_impl("interpret") == "interpret"
+    monkeypatch.setattr(ops, "_backend", lambda: "tpu")
+    assert ops.get_default_impl() == "pallas"
+    assert ops.resolve_impl(None) == "pallas"
+
+
+def test_pallas_requested_off_tpu_raises():
+    table = jnp.ones((16, 8), jnp.float32)
+    idx = jnp.zeros((2, 3), jnp.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        ops.embedding_bag(table, idx, plan=EmbeddingPlan(), impl="pallas")
+    with pytest.raises(ValueError, match="unknown kernel impl"):
+        ops.resolve_impl("cuda")
+
+
+def test_pinned_impl_is_used_and_reset():
+    try:
+        ops.set_default_impl("interpret")
+        assert ops.resolve_impl(None) == "interpret"
+    finally:
+        ops.set_default_impl(None)
+    assert ops.get_default_impl() == "xla"

@@ -24,11 +24,12 @@ _CHILD = textwrap.dedent("""
     from repro.models.registry import build_model
     from repro.train import optim, trainer, elastic
     from repro.core.flash_checkpoint import FlashCheckpoint
+    from repro.launch.mesh import make_local_mesh
 
     assert len(jax.devices()) == 8
 
     # ---- rule resolution for every (arch x shape) on a 4x2 mesh ----------
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(4, 2)
     for arch, cfg in ARCHS.items():
         for shape in SHAPES.values():
             pol = make_policy(mesh, cfg, shape)
@@ -52,7 +53,7 @@ _CHILD = textwrap.dedent("""
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=32, global_batch=8)
 
     def run_steps(mesh_shape, state_host, n, ckpt):
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_local_mesh(*mesh_shape)
         pol = make_policy(mesh, cfg, shape)
         with mesh, use_policy(pol):
             shardings = elastic.state_shardings(api, "adam", pol)
@@ -96,3 +97,68 @@ def test_multidevice_policy_and_elastic_remesh():
                           cwd=os.path.dirname(os.path.dirname(__file__)))
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "MULTIDEVICE_OK" in proc.stdout
+
+
+_DLRM_CHILD = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from repro.configs.dlrm_models import WIDE_DEEP, reduced_dlrm
+    from repro.data.synthetic import criteo_batch
+    from repro.launch.mesh import make_local_mesh
+    from repro.sharding.policy import (padded_layout_for_ranges,
+                                       uniform_vocab_ranges)
+    from repro.train import elastic, optim, trainer
+
+    assert len(jax.devices()) == 4
+    cfg = dataclasses.replace(reduced_dlrm(WIDE_DEEP), zipf_alpha=1.05,
+                              hot_rows_k=8)
+    opt = optim.make("adagrad", 0.05)
+    layout = padded_layout_for_ranges(
+        uniform_vocab_ranges(cfg.total_embedding_rows, 4))
+    plan = cfg.embedding_plan(layout=layout)
+    state0 = trainer.make_dlrm_train_state(cfg, opt, jax.random.PRNGKey(0),
+                                           layout=layout)
+    B = cfg.batch_size
+    batches = [{k: jnp.asarray(v) for k, v in criteo_batch(
+        cfg, 11, np.arange(s * B, (s + 1) * B)).items()} for s in range(3)]
+
+    def run(step_fn, state, place=lambda b: b):
+        out = []
+        for b in batches:
+            state, m = step_fn(state, place(b))
+            out.append(float(m["loss"]))
+        return state, out
+
+    one = jax.jit(trainer.make_dlrm_train_step(cfg, opt, plan=plan))
+    _, ref = run(one, jax.device_put(state0, jax.devices()[0]))
+
+    mesh = make_local_mesh(data=1, model=4)
+    step, shardings, batch_sh = elastic.make_dlrm_mesh_step(
+        cfg, opt, "adagrad", mesh, plan)
+    state = jax.device_put(state0, shardings)
+    pool = state["params"]["tables"]
+    assert len(pool.sharding.device_set) == 4
+    assert pool.addressable_shards[0].data.shape[0] == 1   # one PS shard each
+    end, got = run(step, state, lambda b: jax.device_put(b, batch_sh))
+    assert end["params"]["tables"].sharding.is_equivalent_to(
+        shardings["params"]["tables"], 3)
+    np.testing.assert_allclose(got, ref, rtol=1e-6)
+    print("MESH_DLRM_OK", ref, got)
+""")
+
+
+def test_sharded_dlrm_step_matches_one_device():
+    """The pooled store sharded one PS shard per device over a (1, 4) mesh
+    (``elastic.make_dlrm_mesh_step``, the chip smoke's four-chip path at
+    reduced size) trains with the single-device losses."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _DLRM_CHILD], env=env,
+                          capture_output=True, text=True, timeout=600,
+                          cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "MESH_DLRM_OK" in proc.stdout
