@@ -16,10 +16,13 @@ made from fixed seeds. Every phase goes through the entry points users run:
                checkpoint: losses finite and falling, at least one re-plan,
                and the resumed first loss equal to the original run's loss
                at that step.
-3. reference - on one batch and state: the Pallas step's loss and pooled-
-               store gradients against ``impl="xla"`` (the plain float32
-               reference), and the ``--fused-update`` sparse step against
-               the dense step.
+3. reference - the Pallas kernels against ``impl="xla"`` (the plain float32
+               reference): loss and pooled-store gradients on one batch;
+               5 adagrad steps run on both, per-step losses and the state
+               they end in; then a re-plan of that state (new hot-row cache
+               plan, permuted and re-padded rows) and one more step on
+               both. Last, the ``--fused-update`` sparse step against the
+               dense step.
 4. reexec    - ``--chaos-proc kill@5`` with one worker at full width: the
                job master re-execs the SIGKILLed worker, which resumes from
                the newest valid checkpoint, and the merged loss log must
@@ -56,9 +59,14 @@ RESULT = "RESULT "           # prefix of a child's result line
 
 # the job every phase trains: full published width, zipf traffic, cache on,
 # padded onto 4 PS shards
-JOB_ARGS = ["--arch", "wide_deep", "--full", "--zipf-alpha", "1.05",
-            "--hot-rows", "64", "--padded-shards", "--n-ps", "4",
-            "--lr", "0.05"]
+ZIPF_ALPHA, HOT_ROWS, N_PS, LR = 1.05, 64, 4, 0.05
+JOB_ARGS = ["--arch", "wide_deep", "--full", "--zipf-alpha", str(ZIPF_ALPHA),
+            "--hot-rows", str(HOT_ROWS), "--padded-shards", "--n-ps",
+            str(N_PS), "--lr", str(LR)]
+TRAIN_STEPS, REPLAN_EVERY = 20, 5     # launcher steps; re-plan poll period
+REF_STEPS = 5                         # steps compared against the reference
+REEXEC_STEPS, KILL_AT = 8, 5          # worker steps; SIGKILL before this one
+FOUR_CHIP_STEPS = 3
 # f32 sums taken in another order (kernel vs XLA reduce, Mosaic vs XLA
 # sqrt/divide): max |a - b| / max |b| over each compared array
 REL_BOUND = 1e-5
@@ -91,12 +99,13 @@ def _rel(a, b) -> float:
     return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
 
 
-def phase_train(job_args=JOB_ARGS, steps: int = 20, every: int = 5) -> dict:
-    """20 launcher steps with live re-plans, then a fresh resumed call."""
+def phase_train() -> dict:
+    """Launcher steps with live re-plans, then a fresh resumed call."""
     from repro.launch import train
+    steps, every = TRAIN_STEPS, REPLAN_EVERY
     ckpt_dir = tempfile.mkdtemp(prefix="smoke_ckpt_")
     try:
-        args = job_args + ["--ckpt-dir", ckpt_dir, "--ckpt-every", str(every)]
+        args = JOB_ARGS + ["--ckpt-dir", ckpt_dir, "--ckpt-every", str(every)]
         run = train.main(args + ["--steps", str(steps),
                                  "--replan-every", str(every)])
         losses = [run["losses"][s] for s in sorted(run["losses"])]
@@ -129,93 +138,172 @@ def phase_train(job_args=JOB_ARGS, steps: int = 20, every: int = 5) -> dict:
     return out
 
 
-def _job(full: bool = True):
-    """The smoke's job at full width (or reduced, for a CPU rehearsal)."""
+def _job():
+    """The smoke's job at full width, its padded layout and fresh state."""
     import dataclasses
 
     import jax
-    from repro.configs.dlrm_models import WIDE_DEEP, reduced_dlrm
+    from repro.configs.dlrm_models import WIDE_DEEP
     from repro.sharding.policy import (padded_layout_for_ranges,
                                        uniform_vocab_ranges)
     from repro.train import optim, trainer
-    cfg = WIDE_DEEP if full else reduced_dlrm(WIDE_DEEP)
-    cfg = dataclasses.replace(cfg, zipf_alpha=1.05, hot_rows_k=64)
-    opt = optim.make("adagrad", 0.05)
+    cfg = dataclasses.replace(WIDE_DEEP, zipf_alpha=ZIPF_ALPHA,
+                              hot_rows_k=HOT_ROWS)
+    opt = optim.make("adagrad", LR)
     layout = padded_layout_for_ranges(
-        uniform_vocab_ranges(cfg.total_embedding_rows, 4))
+        uniform_vocab_ranges(cfg.total_embedding_rows, N_PS))
     state = trainer.make_dlrm_train_state(cfg, opt, jax.random.PRNGKey(0),
                                           layout=layout)
     return cfg, opt, layout, state
 
 
-def _batches(cfg, n: int):
-    import jax.numpy as jnp
+def _raw_batches(cfg, n: int):
+    """The launcher's first ``n`` batches, as host arrays."""
     import numpy as np
     from repro.data.synthetic import criteo_batch
     B = cfg.batch_size
-    return [{k: jnp.asarray(v) for k, v in criteo_batch(
-        cfg, 11, np.arange(s * B, (s + 1) * B)).items()} for s in range(n)]
+    return [criteo_batch(cfg, 11, np.arange(s * B, (s + 1) * B))
+            for s in range(n)]
 
 
-def phase_reference(full: bool = True) -> dict:
-    """Pallas vs the float32 XLA reference; sparse vs dense step."""
+def _on_device(batch: dict) -> dict:
+    import jax.numpy as jnp
+    return {k: jnp.asarray(v) for k, v in batch.items()}
+
+
+def _compiled(fn, impl: str, *args):
+    """``fn`` traced and compiled with the embedding kernels pinned to
+    ``impl``; the Pallas program must hold a kernel and the reference none.
+
+    A new function per call: jit caches traces by function, and the impl is
+    read when the function is traced.
+    """
+    import jax
+    from repro.kernels import ops
+    ops.set_default_impl(impl)
+    try:
+        compiled = jax.jit(lambda *a: fn(*a)).lower(*args).compile()
+    finally:
+        ops.set_default_impl(None)
+    kernel = "tpu_custom_call" in compiled.as_text()
+    _check(kernel == (impl == "pallas"),
+           f"{impl} program holds a Pallas kernel: {kernel}")
+    return compiled
+
+
+def _run_steps(step, state, batches):
+    losses = []
+    for b in batches:
+        state, m = step(state, b)
+        losses.append(float(m["loss"]))
+    return losses, state
+
+
+def _state_diffs(diffs: dict, tag: str, got, ref) -> None:
+    for k in ("tables", "wide"):
+        diffs[f"{tag}_params_{k}"] = _rel(got["params"][k], ref["params"][k])
+        diffs[f"{tag}_acc_{k}"] = _rel(got["opt"]["acc"][k],
+                                       ref["opt"]["acc"][k])
+
+
+def phase_reference() -> dict:
+    """Pallas vs the float32 XLA reference over steps and a re-plan; sparse
+    vs dense step."""
     import jax
     import numpy as np
+    from repro.core.sharding_service import HotTableTracker
     from repro.kernels import ops
     from repro.models.dlrm import dlrm_loss
-    from repro.train import trainer
-    cfg, opt, layout, state = _job(full)
+    from repro.train import replan, trainer
+    cfg, opt, layout, state0 = _job()
     plan = cfg.embedding_plan(layout=layout)
-    (batch,) = _batches(cfg, 1)
-    impl = ops.get_default_impl()
-    on_tpu = jax.default_backend() == "tpu"
-    _check(impl == ("pallas" if on_tpu else "interpret"),
-           f"kernel impl {impl!r} on {jax.default_backend()}")
-
-    def loss_and_grads():
-        # a new function per call: jit caches traces by function, and the
-        # impl is read when the step is traced
-        return jax.jit(jax.value_and_grad(
-            lambda q: dlrm_loss(q, batch, cfg, plan=plan)))
-
-    def run(fn):
-        compiled = fn.lower(state["params"]).compile()
-        kernel = "tpu_custom_call" in compiled.as_text()
-        return kernel, compiled(state["params"])
-
-    diffs = {}
+    raw = _raw_batches(cfg, REF_STEPS + 1)
+    batches = [_on_device(b) for b in raw[:REF_STEPS]]
+    _check(ops.get_default_impl() == "pallas",
+           f"kernel impl {ops.get_default_impl()!r} on the TPU")
+    diffs, losses = {}, {}
     # float32 matmuls on both sides: only the embedding path differs
     with jax.default_matmul_precision("float32"):
-        kernel, (l_k, g_k) = run(loss_and_grads())
-        _check(kernel == on_tpu, f"Pallas kernel in the step: {kernel}")
-        ops.set_default_impl("xla")
-        try:
-            kernel, (l_x, g_x) = run(loss_and_grads())
-        finally:
-            ops.set_default_impl(None if on_tpu else impl)
-        _check(not kernel, "the XLA reference step runs a Pallas kernel")
-    diffs["loss"] = _rel(l_k, l_x)
-    for k in ("tables", "wide"):
-        diffs[f"grad_{k}"] = _rel(g_k[k], g_x[k])
-        _check(float(np.max(np.abs(np.asarray(g_x[k])))) > 0,
-               f"zero reference gradient for {k}")
+        # loss and pooled-store gradients on one batch
+        grads = {}
+        for impl in ("pallas", "xla"):
+            fn = _compiled(jax.value_and_grad(lambda q: dlrm_loss(
+                q, batches[0], cfg, plan=plan)), impl, state0["params"])
+            grads[impl] = fn(state0["params"])
+        (l_k, g_k), (l_x, g_x) = grads["pallas"], grads["xla"]
+        diffs["loss"] = _rel(l_k, l_x)
+        for k in ("tables", "wide"):
+            diffs[f"grad_{k}"] = _rel(g_k[k], g_x[k])
+            _check(float(np.max(np.abs(np.asarray(g_x[k])))) > 0,
+                   f"zero reference gradient for {k}")
+        del grads, g_k, g_x
 
-    dense = jax.jit(trainer.make_dlrm_train_step(cfg, opt, plan=plan))
-    sparse = jax.jit(trainer.make_dlrm_train_step(
-        cfg, opt, plan=cfg.embedding_plan(layout=layout, sparse_update=True)))
-    s_d, m_d = dense(state, batch)
-    s_s, m_s = sparse(state, batch)
-    diffs["sparse_loss"] = _rel(m_s["loss"], m_d["loss"])
-    for k in ("tables", "wide"):
-        diffs[f"sparse_{k}"] = _rel(s_s["params"][k], s_d["params"][k])
-        diffs[f"sparse_acc_{k}"] = _rel(s_s["opt"]["acc"][k],
-                                        s_d["opt"]["acc"][k])
+        # REF_STEPS adagrad steps on each side, from the same state
+        steps, ends = {}, {}
+        for impl in ("pallas", "xla"):
+            steps[impl] = _compiled(trainer.make_dlrm_train_step(
+                cfg, opt, plan=plan), impl, state0, batches[0])
+            losses[impl], ends[impl] = _run_steps(steps[impl], state0,
+                                                  batches)
+        for s, (a, b) in enumerate(zip(losses["pallas"], losses["xla"])):
+            diffs[f"step{s}_loss"] = _rel(a, b)
+        _state_diffs(diffs, f"step{REF_STEPS - 1}", ends["pallas"],
+                     ends["xla"])
+
+        # a re-plan of the reference's state, as the launcher applies one:
+        # rows permuted and re-padded, a measured hot-row cache plan
+        tracker = HotTableTracker(
+            cfg.table_rows, n_ps=N_PS, hot_budget=cfg.hot_rows_k,
+            cooldown=REF_STEPS,
+            min_lookups=4 * cfg.batch_size * cfg.n_tables * cfg.multi_hot)
+        for b in raw[:REF_STEPS]:
+            tracker.observe(b["sparse"])
+        decision = tracker.maybe_replan()
+        _check(decision is not None, "the tracker planned no re-plan")
+        remapper = replan.EmbeddingRemapper(cfg.table_rows)
+        res = replan.apply_replan(ends["xla"], cfg, opt, decision,
+                                  remapper=remapper, layout=layout,
+                                  plan=plan)
+        del ends
+        hot_after = res.plan.table_hot
+        _check(hot_after != plan.table_hot,
+               f"the re-plan kept the cache plan {plan.table_hot}")
+        nxt = _on_device(remapper.remap_batch(raw[REF_STEPS]))
+        after = {}
+        for impl in ("pallas", "xla"):
+            fn = _compiled(trainer.make_dlrm_train_step(
+                cfg, opt, plan=res.plan), impl, res.state, nxt)
+            (loss,), after[impl] = _run_steps(fn, res.state, [nxt])
+            losses[f"replan_{impl}"] = loss
+        diffs["replan_loss"] = _rel(losses["replan_pallas"],
+                                    losses["replan_xla"])
+        _state_diffs(diffs, "replan", after["pallas"], after["xla"])
+        del after, res
+
+        # the --fused-update sparse step against the dense step (Pallas)
+        sparse = _compiled(trainer.make_dlrm_train_step(
+            cfg, opt, plan=cfg.embedding_plan(layout=layout,
+                                              sparse_update=True)),
+            "pallas", state0, batches[0])
+        s_d, m_d = steps["pallas"](state0, batches[0])
+        s_s, m_s = sparse(state0, batches[0])
+        diffs["sparse_loss"] = _rel(m_s["loss"], m_d["loss"])
+        _state_diffs(diffs, "sparse", s_s, s_d)
+
+    print(f"phase reference: per-step losses pallas {losses['pallas']} "
+          f"xla {losses['xla']}", flush=True)
+    print(f"phase reference: re-plan at step {REF_STEPS} (imbalance "
+          f"{decision.imbalance_before:.3f} -> "
+          f"{decision.imbalance_after:.3f}, cache plan {plan.table_hot} -> "
+          f"{hot_after}); next-step loss pallas "
+          f"{losses['replan_pallas']!r} xla {losses['replan_xla']!r}",
+          flush=True)
     for name, d in diffs.items():
         print(f"phase reference: {name} max|diff|/max|ref| = {d:.3e} "
               f"(bound {REL_BOUND:.0e})", flush=True)
     bad = {k: d for k, d in diffs.items() if not d <= REL_BOUND}
     _check(not bad, f"over the bound {REL_BOUND}: {bad}")
-    return {"impl": impl, "diffs": diffs, "bound": REL_BOUND,
+    return {"diffs": diffs, "bound": REL_BOUND, "losses": losses,
             "loss_kernel": float(l_k), "loss_xla": float(l_x)}
 
 
@@ -230,15 +318,15 @@ def _collectives(text: str) -> dict:
     return out
 
 
-def phase_four_chips(full: bool = True, n_steps: int = 3) -> dict:
+def phase_four_chips() -> dict:
     """Sharded pooled store on (data=1, model=4) vs the same steps on one
     device, in one process."""
     import jax
     from repro.launch.mesh import make_local_mesh
     from repro.train import elastic, trainer
-    cfg, opt, layout, state0 = _job(full)
+    cfg, opt, layout, state0 = _job()
     plan = cfg.embedding_plan(layout=layout)
-    batches = _batches(cfg, n_steps)
+    batches = [_on_device(b) for b in _raw_batches(cfg, FOUR_CHIP_STEPS)]
 
     one = jax.jit(trainer.make_dlrm_train_step(cfg, opt, plan=plan))
     state = jax.device_put(state0, jax.devices()[0])
@@ -355,17 +443,17 @@ def _merged_losses(workdir: str) -> dict:
     return merged
 
 
-def phase_reexec(deadline: float, job_args=JOB_ARGS, steps: int = 8,
-                 kill_at: int = 5) -> dict:
-    """The job master SIGKILLs the worker before step ``kill_at``; the
+def phase_reexec(deadline: float) -> dict:
+    """The job master SIGKILLs the worker before step ``KILL_AT``; the
     re-exec'd worker must resume and reproduce the no-fault loss log."""
+    steps, kill_at = REEXEC_STEPS, KILL_AT
     logs = {}
     summary = ""
     root = tempfile.mkdtemp(prefix="smoke_reexec_")
     try:
         for plan in ("", f"kill@{kill_at}"):
             workdir = os.path.join(root, plan or "nofault")
-            out = _run([sys.executable, "-m", "repro.launch.train", *job_args,
+            out = _run([sys.executable, "-m", "repro.launch.train", *JOB_ARGS,
                         "--steps", str(steps), "--ckpt-every", "2",
                         "--heartbeat-deadline", "120",
                         "--chaos-proc", plan, "--workdir", workdir], deadline)

@@ -324,9 +324,11 @@ def _fused_kernel(idx_ref, nxt_ref, lines_ref, *refs,
         reduce_from(stage_b)
 
 
-def _pallas_forward(pool, enc_idx, weights, cache, *, B, T, H, combiner,
+def _pallas_forward(pool, enc_idx, weights, cache, *, T, H, combiner,
                     block_b, interpret):
     R, D = pool.shape
+    B = enc_idx.shape[0]              # this device's batch shard on a mesh
+    block_b = min(block_b, B)
     P = rows_per_line(D)
     lines = lane_pack(pool)                                     # (L, W)
     L, W = lines.shape
@@ -567,14 +569,18 @@ def _fused(pool, flat_idx, weights, meta):
             enc = flat_idx.reshape(B, T, H)
             if layout is not None:
                 enc = translate_rows(enc, layout)
-        fwd = functools.partial(_pallas_forward, B=B, T=T, H=H,
+        fwd = functools.partial(_pallas_forward, T=T, H=H,
                                 combiner=combiner, block_b=block_b,
                                 interpret=(method == "interpret"))
-        if mesh is not None and method == "pallas":
-            # GSPMD cannot partition a Mosaic kernel: every device runs it
-            # on the whole pool, which is all-gathered (replicated) around it
-            fwd = jax.shard_map(fwd, mesh=mesh, in_specs=P(), out_specs=P(),
-                                check_vma=False)
+        if mesh is not None:
+            # GSPMD cannot partition a Mosaic kernel: each device runs it on
+            # its own batch shard against the whole pool, which is
+            # all-gathered (replicated) around it
+            mesh, batch_axes = mesh
+            rows = P(batch_axes or None)
+            fwd = jax.shard_map(fwd, mesh=mesh,
+                                in_specs=(P(), rows, rows, P()),
+                                out_specs=rows, check_vma=False)
         return fwd(pool, enc, weights, cache)
     # XLA path: under frequency-packed placement the hot prefixes are already
     # contiguous in the pool and stay hardware-cache-resident; a separate
@@ -625,7 +631,8 @@ def fused_embedding_bag(pool: jnp.ndarray, indices: jnp.ndarray,
                         combiner: str = "sum", method: str = "xla",
                         block_b: int = 8,
                         table_hot: Optional[Sequence[int]] = None,
-                        layout=None, plan=None, mesh=None) -> jnp.ndarray:
+                        layout=None, plan=None, mesh=None,
+                        batch_axes: Sequence[str] = ()) -> jnp.ndarray:
     """Pool per-table embedding bags for all tables in one fused call.
 
     Args:
@@ -660,8 +667,10 @@ def fused_embedding_bag(pool: jnp.ndarray, indices: jnp.ndarray,
                  in one hashable value (overrides the loose kwargs; the
                  preferred form — see ``kernels/ops.py``).
       mesh:      the device mesh the calling step is partitioned over, if
-                 any; the Pallas kernel then runs replicated on every device
-                 (a Mosaic kernel is not partitioned by GSPMD).
+                 any; the Pallas kernel then runs on each device's shard of
+                 the batch with the pool replicated (a Mosaic kernel is not
+                 partitioned by GSPMD).
+      batch_axes: the mesh axes the batch dim is split over (with ``mesh``).
 
     Returns (B, T, D); gradients flow to ``pool`` (sparse scatter-add of
     the deduped row cotangents, into padded rows under ``layout``) and
@@ -693,6 +702,7 @@ def fused_embedding_bag(pool: jnp.ndarray, indices: jnp.ndarray,
             hot = (offs, table_hot)
     flat_idx = idx.reshape(-1)
     w = None if weights is None else weights.astype(jnp.float32)
+    spmd = None if mesh is None else (mesh, tuple(batch_axes))
     meta = (combiner, B, T, H, method, max(1, min(block_b, B)), hot, layout,
-            mesh)
+            spmd)
     return _fused(pool, flat_idx, w, meta)

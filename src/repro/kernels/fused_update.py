@@ -1,4 +1,4 @@
-"""Fused row-wise optimizer updates: touched rows only, moments in place.
+"""Fused row-wise optimizer updates: the update arithmetic on touched rows only.
 
 The dense training path materializes a (R, D) gradient for every pooled
 embedding store and lets the optimizer touch all ~R rows per step, even
@@ -25,8 +25,11 @@ Pallas kernel
     pools stay off-chip (``memory_space=ANY``), lane-packed
     (``common.lane_pack``: Mosaic moves HBM data in 128-lane lines, and a
     ``(R, 16)`` or ``(R, 1)`` row is narrower), and aliased input→output
-    (``input_output_aliases``) so the update is in place. Per row, the
-    kernel DMAs the parameter/moment lines holding the row into ``(1, W)``
+    (``input_output_aliases``). The state holds the pools as ``(R, D)``,
+    so each call copies every pool into its lines and back afterwards: the
+    aliased buffer is that temporary, and the call reads and writes all
+    ``R`` rows of each pool even though the kernel touches a few. Per row,
+    the kernel DMAs the parameter/moment lines holding the row into ``(1, W)``
     VMEM staging, rotates the value row onto the row's lanes, applies the
     *same* f32 expressions as the XLA fallback on those lanes only, and
     DMAs the lines back — guarded by ``pl.when(row < R)`` so the sentinel

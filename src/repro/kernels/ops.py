@@ -122,7 +122,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, pos, *, window=None,
 
 def fused_embedding_bag(pool, indices, weights=None, *, plan=None, impl=None,
                         offsets=None, combiner=None, block_b=None,
-                        table_hot=None, layout=None):
+                        table_hot=None, layout=None, mesh=None, batch_axes=()):
     """Multi-table fused embedding engine (one call for all tables).
 
     pool (R, D) row-concatenated tables — or, with a padded ``plan.layout``
@@ -140,6 +140,11 @@ def fused_embedding_bag(pool, indices, weights=None, *, plan=None, impl=None,
     onto new ones. All impls share a custom VJP whose backward dedupes and
     scatter-adds sparse table gradients.
 
+    ``mesh`` is the device mesh the calling step is partitioned over, and
+    ``batch_axes`` the mesh axes its batch is split over: the Pallas kernel
+    then runs on each device's batch shard with the pool replicated (GSPMD
+    cannot partition a Mosaic kernel).
+
     The loose ``offsets``/``combiner``/``block_b``/``table_hot``/``layout``
     kwargs are deprecated (warn-once shim building a plan internally).
     """
@@ -151,9 +156,8 @@ def fused_embedding_bag(pool, indices, weights=None, *, plan=None, impl=None,
                 and table_hot is None and layout is None), \
             "pass the static knobs inside plan=, not alongside it"
     from repro.kernels import fused_embedding as fe
-    from repro.sharding.policy import current_policy
     return fe.fused_embedding_bag(pool, indices, weights, method=impl,
-                                  plan=plan, mesh=current_policy().mesh)
+                                  plan=plan, mesh=mesh, batch_axes=batch_axes)
 
 
 def sparse_row_grads(pool, indices, g, weights=None, *, plan):
@@ -171,7 +175,7 @@ def sparse_row_grads(pool, indices, g, weights=None, *, plan):
 
 def fused_row_update(params, rows, vals, *state, kind, impl=None, block=8,
                      **hyper):
-    """Row-wise optimizer update on deduped COO row grads (in place).
+    """Row-wise optimizer update on deduped COO row grads.
 
     params (R, D) pool; rows (N,) deduplicated store rows (entries >= R are
     inert padding); vals (N, D) summed row grads; ``state`` the optimizer's

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from repro.configs.dlrm_models import DLRMConfig
 from repro.kernels import ops
 from repro.models.common import KeyGen, dense_init
-from repro.sharding.policy import constrain
+from repro.sharding.policy import constrain, current_policy
 
 
 def init_dlrm(cfg: DLRMConfig, key, layout=None) -> Dict[str, Any]:
@@ -143,6 +143,14 @@ def sparse_param_keys(cfg: DLRMConfig) -> tuple:
     return ("tables", "wide") if cfg.kind == "wide_deep" else ("tables",)
 
 
+def _bag(pool, sparse, plan):
+    """One fused lookup, partitioned like the step that traces it: under a
+    mesh the kernel runs per batch shard with the pool replicated."""
+    pol = current_policy()
+    return ops.fused_embedding_bag(pool, sparse, plan=plan, mesh=pol.mesh,
+                                   batch_axes=pol.rules.get("batch", ()))
+
+
 def dlrm_embeddings(params, batch, cfg: DLRMConfig, plan) -> Dict[str, Any]:
     """Every pooled-store lookup of one forward, in one dict.
 
@@ -154,12 +162,11 @@ def dlrm_embeddings(params, batch, cfg: DLRMConfig, plan) -> Dict[str, Any]:
     Returns ``{"deep": (B, n_tables, D)}`` plus ``{"wide": (B, n_tables, 1)}``
     for wide_deep.
     """
-    embs = {"deep": ops.fused_embedding_bag(
-        _pool2d(params["tables"], plan.layout), batch["sparse"], plan=plan)}
+    embs = {"deep": _bag(_pool2d(params["tables"], plan.layout),
+                         batch["sparse"], plan)}
     if cfg.kind == "wide_deep":
-        embs["wide"] = ops.fused_embedding_bag(
-            _pool2d(params["wide"], plan.layout), batch["sparse"],
-            plan=plan.with_combiner("sum"))
+        embs["wide"] = _bag(_pool2d(params["wide"], plan.layout),
+                            batch["sparse"], plan.with_combiner("sum"))
     return embs
 
 
@@ -167,8 +174,7 @@ def _field_embeddings(params, batch, cfg: DLRMConfig, table_hot=None,
                       layout=None, plan=None):
     """All per-field embeddings in ONE fused call. -> (B, n_tables, D)."""
     plan = _resolve_plan(cfg, plan, table_hot, layout)
-    return ops.fused_embedding_bag(
-        _pool2d(params["tables"], plan.layout), batch["sparse"], plan=plan)
+    return _bag(_pool2d(params["tables"], plan.layout), batch["sparse"], plan)
 
 
 def _deep_mlp(params, x, cfg: DLRMConfig):

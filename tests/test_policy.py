@@ -100,19 +100,21 @@ def test_multidevice_policy_and_elastic_remesh():
 
 
 _DLRM_CHILD = textwrap.dedent("""
-    import os
+    import os, sys
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import dataclasses
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs.dlrm_models import WIDE_DEEP, reduced_dlrm
     from repro.data.synthetic import criteo_batch
+    from repro.kernels import ops
     from repro.launch.mesh import make_local_mesh
     from repro.sharding.policy import (padded_layout_for_ranges,
                                        uniform_vocab_ranges)
     from repro.train import elastic, optim, trainer
 
     assert len(jax.devices()) == 4
+    data, model = int(sys.argv[1]), int(sys.argv[2])
     cfg = dataclasses.replace(reduced_dlrm(WIDE_DEEP), zipf_alpha=1.05,
                               hot_rows_k=8)
     opt = optim.make("adagrad", 0.05)
@@ -135,30 +137,42 @@ _DLRM_CHILD = textwrap.dedent("""
     one = jax.jit(trainer.make_dlrm_train_step(cfg, opt, plan=plan))
     _, ref = run(one, jax.device_put(state0, jax.devices()[0]))
 
-    mesh = make_local_mesh(data=1, model=4)
-    step, shardings, batch_sh = elastic.make_dlrm_mesh_step(
-        cfg, opt, "adagrad", mesh, plan)
-    state = jax.device_put(state0, shardings)
-    pool = state["params"]["tables"]
-    assert len(pool.sharding.device_set) == 4
-    assert pool.addressable_shards[0].data.shape[0] == 1   # one PS shard each
-    end, got = run(step, state, lambda b: jax.device_put(b, batch_sh))
-    assert end["params"]["tables"].sharding.is_equivalent_to(
-        shardings["params"]["tables"], 3)
-    np.testing.assert_allclose(got, ref, rtol=1e-6)
+    mesh = make_local_mesh(data=data, model=model)
+    got = {}
+    # "interpret" runs the kernel bodies per batch shard under shard_map,
+    # the path a TPU mesh takes; "xla" is partitioned by GSPMD
+    for impl in ("xla", "interpret"):
+        ops.set_default_impl(impl)
+        step, shardings, batch_sh = elastic.make_dlrm_mesh_step(
+            cfg, opt, "adagrad", mesh, plan)
+        state = jax.device_put(state0, shardings)
+        pool = state["params"]["tables"]
+        assert len(pool.sharding.device_set) == 4
+        # n_ps / model PS shards on each device
+        assert pool.addressable_shards[0].data.shape[0] == 4 // model
+        end, got[impl] = run(step, state, lambda b: jax.device_put(b, batch_sh))
+        assert end["params"]["tables"].sharding.is_equivalent_to(
+            shardings["params"]["tables"], 3)
+    np.testing.assert_allclose(got["xla"], ref, rtol=1e-6)
+    # f32 bag sums in the kernel's order vs XLA's reduce
+    np.testing.assert_allclose(got["interpret"], ref, rtol=1e-5)
     print("MESH_DLRM_OK", ref, got)
 """)
 
 
-def test_sharded_dlrm_step_matches_one_device():
-    """The pooled store sharded one PS shard per device over a (1, 4) mesh
-    (``elastic.make_dlrm_mesh_step``, the chip smoke's four-chip path at
-    reduced size) trains with the single-device losses."""
+@pytest.mark.parametrize("data,model", [(1, 4), (2, 2)])
+def test_sharded_dlrm_step_matches_one_device(data, model):
+    """The pooled store sharded over the "model" axis of a (1, 4) and a
+    (2, 2) mesh (``elastic.make_dlrm_mesh_step``, the chip smoke's
+    four-chip path at reduced size) trains with the single-device losses,
+    through the XLA lookup and through the kernel bodies run per batch
+    shard."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, "-c", _DLRM_CHILD], env=env,
-                          capture_output=True, text=True, timeout=600,
-                          cwd=os.path.dirname(os.path.dirname(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", _DLRM_CHILD, str(data), str(model)], env=env,
+        capture_output=True, text=True, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(__file__)))
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "MESH_DLRM_OK" in proc.stdout

@@ -8,7 +8,8 @@ the installed TPU compiler compiles, at the published ``wide_deep`` widths
 * the fused embedding forward, plain and with the hot-row cache + weights;
 * the adagrad and adam row-update kernels;
 * the whole train step, dense and with the fused sparse update;
-* the dense step over all four chips of the host, pooled store on "model".
+* the dense step over all four chips of the host, pooled store on "model"
+  and batch on "data", as (data=1, model=4) and (data=2, model=2).
 
 Each compiled program must contain the kernel (``tpu_custom_call``) and fit
 one chip's 16 GB of HBM. Nothing runs: these are compiles, not chip runs.
@@ -17,6 +18,7 @@ every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU compiler.
 """
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -129,22 +131,30 @@ def test_full_width_train_step_compiles(one_chip, tpu_backend, sparse_update):
                                                           else 2)
 
 
-def test_sharded_train_step_compiles_on_four_chips(topo, tpu_backend):
-    """The pooled store split one PS shard per chip over (data=1, model=4):
-    GSPMD cannot partition a Mosaic kernel, so the step all-gathers each
-    pool and runs the kernel replicated (``shard_map``) — and must compile."""
+def _nbytes(x, shard_shape=None):
+    return math.prod(shard_shape or x.shape) * jnp.dtype(x.dtype).itemsize
+
+
+@pytest.mark.parametrize("data,model", [(1, 4), (2, 2)])
+def test_sharded_train_step_compiles_on_four_chips(topo, tpu_backend, data,
+                                                   model):
+    """The pooled store split over the "model" axis of the host's four chips,
+    with the batch split over "data": GSPMD cannot partition a Mosaic
+    kernel, so the step all-gathers each pool and runs the kernel on each
+    chip's batch shard (``shard_map``) — and must compile."""
     from repro.launch.mesh import make_local_mesh
     from repro.train import elastic
     opt = optim.make("adagrad", 0.05)
     layout = padded_layout_for_ranges(uniform_vocab_ranges(R, 4))
     plan = CFG.embedding_plan(layout=layout)
-    mesh = make_local_mesh(data=1, model=4, devices=topo.devices)
+    mesh = make_local_mesh(data=data, model=model, devices=topo.devices)
     step, shardings, batch_sh = elastic.make_dlrm_mesh_step(
         CFG, opt, "adagrad", mesh, plan)
+    shapes = jax.eval_shape(lambda k: trainer.make_dlrm_train_state(
+        CFG, opt, k, layout=layout), jax.random.PRNGKey(0))
     state = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        jax.eval_shape(lambda k: trainer.make_dlrm_train_state(
-            CFG, opt, k, layout=layout), jax.random.PRNGKey(0)), shardings)
+        shapes, shardings)
     batch = {"dense": _spec(batch_sh, (B, CFG.n_dense)),
              "sparse": _spec(batch_sh, (B, T, H), jnp.int32),
              "label": _spec(batch_sh, (B,))}
@@ -152,7 +162,14 @@ def test_sharded_train_step_compiles_on_four_chips(topo, tpu_backend):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     ma = compiled.memory_analysis()          # per device
-    assert ma.argument_size_in_bytes < 0.3 * 451_420_672   # pools split 4 ways
+    # the arguments are each chip's shards, not the one-chip state
+    leaves = jax.tree.leaves(state) + jax.tree.leaves(batch)
+    shard_bytes = sum(_nbytes(x, x.sharding.shard_shape(x.shape))
+                      for x in leaves)
+    whole_bytes = sum(_nbytes(x) for x in jax.tree.leaves(shapes))
+    assert shard_bytes < 1.1 * whole_bytes / model
+    assert ma.argument_size_in_bytes <= 1.01 * shard_bytes, (
+        ma.argument_size_in_bytes, shard_bytes)
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < HBM_BYTES
     # the tables pool comes back whole around the kernel
