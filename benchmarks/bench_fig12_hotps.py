@@ -110,7 +110,7 @@ def run() -> List[Row]:
     # --- hot-PS at placement time: skewed rows -> cache + balanced ranges ---
     # The same power-law row popularity that overloads one PS is what the
     # fused embedding engine's hot-row cache and the RecShard-style placement
-    # plan exploit (see bench_kernels' skew section for the wall-time side).
+    # plan exploit.
     import dataclasses as _dc
     from repro.configs.dlrm_models import WIDE_DEEP, reduced_dlrm
     from repro.core.sharding_service import ParameterPlacementService

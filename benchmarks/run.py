@@ -28,8 +28,6 @@ BENCHES = [
     ("fig14", "benchmarks.bench_fig14_cluster"),
     ("fig15", "benchmarks.bench_fig15_jct_cdf"),
     ("chaos", "benchmarks.bench_chaos"),
-    ("kernels", "benchmarks.bench_kernels"),
-    ("roofline", "benchmarks.roofline"),
 ]
 
 

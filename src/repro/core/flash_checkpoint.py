@@ -40,6 +40,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.spans import span
+
 logger = logging.getLogger("repro.flash_checkpoint")
 
 _DATA_FILE = "leaves.npz"
@@ -53,9 +55,10 @@ class CheckpointCorruptError(RuntimeError):
 
 def _flatten(state) -> Dict[str, np.ndarray]:
     flat = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
-        key = jax.tree_util.keystr(path)
-        flat[key] = np.asarray(jax.device_get(leaf))
+    with span("ckpt_d2h"):
+        for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+            key = jax.tree_util.keystr(path)
+            flat[key] = np.asarray(jax.device_get(leaf))
     return flat
 
 
@@ -117,6 +120,7 @@ class FlashCheckpoint:
         self._pending: List[Future] = []
         self._lock = threading.Lock()
         self.last_save_seconds = 0.0      # memory-tier latency (critical path)
+        self.last_save_bytes = 0          # device-array bytes the save copied
         self.last_persist_seconds = 0.0   # disk-tier latency (off critical path)
         self.last_restore_seconds = 0.0
         self.events: List[Dict] = []      # skipped dirs, corrupt-blob fallbacks
@@ -149,6 +153,8 @@ class FlashCheckpoint:
                 old = self._mem_order.pop(0)
                 self._mem.pop(old, None)
         self.last_save_seconds = time.perf_counter() - t0
+        self.last_save_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(state)
+                                   if isinstance(leaf, jax.Array))
         if self.persist_dir:
             if self.async_persist:
                 self._pending.append(self._pool.submit(self._persist, flat, step))
@@ -163,33 +169,34 @@ class FlashCheckpoint:
             self._mem_order.clear()
 
     def _persist(self, flat: Dict[str, np.ndarray], step: int) -> None:
-        t0 = time.perf_counter()
-        final = os.path.join(self.persist_dir, f"ckpt_{step:012d}")
-        tmp = final + f".tmp-{os.getpid()}"
-        if os.path.isdir(tmp):
-            shutil.rmtree(tmp)
-        os.makedirs(tmp)
-        with open(os.path.join(tmp, _DATA_FILE), "wb") as f:
-            np.savez(f, **{k: v for k, v in flat.items()})
-            f.flush()
-            os.fsync(f.fileno())
-        manifest = {
-            "format": _FORMAT, "step": int(step),
-            "leaves": {k: {"crc32": _leaf_crc(v),
-                           "shape": list(v.shape), "dtype": str(v.dtype)}
-                       for k, v in flat.items()},
-        }
-        with open(os.path.join(tmp, _MANIFEST_FILE), "w") as f:
-            json.dump(manifest, f)
-            f.flush()
-            os.fsync(f.fileno())
-        if self.pre_commit_hook is not None:     # kill-during-save chaos seam
-            self.pre_commit_hook(tmp, step)
-        self._commit(tmp, final)
-        if self.fault_hook is not None:
-            self.fault_hook(final, step)
-        self._evict()
-        self.last_persist_seconds = time.perf_counter() - t0
+        with span("ckpt_persist"):
+            t0 = time.perf_counter()
+            final = os.path.join(self.persist_dir, f"ckpt_{step:012d}")
+            tmp = final + f".tmp-{os.getpid()}"
+            if os.path.isdir(tmp):
+                shutil.rmtree(tmp)
+            os.makedirs(tmp)
+            with open(os.path.join(tmp, _DATA_FILE), "wb") as f:
+                np.savez(f, **{k: v for k, v in flat.items()})
+                f.flush()
+                os.fsync(f.fileno())
+            manifest = {
+                "format": _FORMAT, "step": int(step),
+                "leaves": {k: {"crc32": _leaf_crc(v),
+                               "shape": list(v.shape), "dtype": str(v.dtype)}
+                           for k, v in flat.items()},
+            }
+            with open(os.path.join(tmp, _MANIFEST_FILE), "w") as f:
+                json.dump(manifest, f)
+                f.flush()
+                os.fsync(f.fileno())
+            if self.pre_commit_hook is not None:     # kill-during-save chaos seam
+                self.pre_commit_hook(tmp, step)
+            self._commit(tmp, final)
+            if self.fault_hook is not None:
+                self.fault_hook(final, step)
+            self._evict()
+            self.last_persist_seconds = time.perf_counter() - t0
 
     def _commit(self, tmp: str, final: str) -> None:
         """THE atomic commit point: one ``os.replace`` of the staging dir.

@@ -35,6 +35,8 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.spans import span
+
 
 @dataclass(frozen=True)
 class Shard:
@@ -447,13 +449,14 @@ class HotTableTracker:
         step actually looks up (post-remap after earlier re-plans), which is
         exactly what workers see and report.
         """
-        sparse = np.asarray(sparse)
-        flat = (sparse.astype(np.int64)
-                + self.offsets[None, :, None]).reshape(-1)
-        with self._lock:
-            self.counts *= self.decay
-            self.counts += np.bincount(flat, minlength=self.total_rows)
-            self._observes += 1
+        with span("observe"):
+            sparse = np.asarray(sparse)
+            flat = (sparse.astype(np.int64)
+                    + self.offsets[None, :, None]).reshape(-1)
+            with self._lock:
+                self.counts *= self.decay
+                self.counts += np.bincount(flat, minlength=self.total_rows)
+                self._observes += 1
 
     def observe_counts(self, delta: np.ndarray) -> None:
         """Fold a pre-binned pooled count delta (heartbeat payload form)."""

@@ -14,6 +14,7 @@ from typing import Callable, Dict, Iterator, Optional
 import numpy as np
 
 from repro.core.sharding_service import Shard, ShardingService
+from repro.spans import span
 
 
 class ShardDataLoader:
@@ -59,26 +60,27 @@ class ShardDataLoader:
         Batches never span shards; a short tail is padded by wrapping within
         the shard (training-only semantics, keeps shapes static for jit).
         """
-        if not self._ensure_shard():
-            return None
-        if self.fault_hook is not None:
-            self.fault_hook(self._batches_emitted)
-        self._batches_emitted += 1
-        shard = self._shard
-        lo = shard.start + self._cursor
-        hi = min(lo + self.batch_size, shard.end)
-        idx = np.arange(lo, hi)
-        if len(idx) < self.batch_size:                    # pad by wrapping
-            extra = np.arange(shard.start,
-                              shard.start + self.batch_size - len(idx))
-            idx = np.concatenate([idx, extra % max(shard.size, 1) + shard.start])
-        self._cursor += self.batch_size
-        self._batches_since_hb += 1
-        if self._batches_since_hb >= self.heartbeat_every:
-            progress = min(self._cursor, shard.size)
-            self.service.heartbeat(self.worker_id, progress, self.clock())
-            self._batches_since_hb = 0
-        return self.batch_fn(idx)
+        with span("loader"):
+            if not self._ensure_shard():
+                return None
+            if self.fault_hook is not None:
+                self.fault_hook(self._batches_emitted)
+            self._batches_emitted += 1
+            shard = self._shard
+            lo = shard.start + self._cursor
+            hi = min(lo + self.batch_size, shard.end)
+            idx = np.arange(lo, hi)
+            if len(idx) < self.batch_size:                    # pad by wrapping
+                extra = np.arange(shard.start,
+                                  shard.start + self.batch_size - len(idx))
+                idx = np.concatenate([idx, extra % max(shard.size, 1) + shard.start])
+            self._cursor += self.batch_size
+            self._batches_since_hb += 1
+            if self._batches_since_hb >= self.heartbeat_every:
+                progress = min(self._cursor, shard.size)
+                self.service.heartbeat(self.worker_id, progress, self.clock())
+                self._batches_since_hb = 0
+            return self.batch_fn(idx)
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         while True:

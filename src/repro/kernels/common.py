@@ -14,6 +14,8 @@ distinction matters:
 """
 from __future__ import annotations
 
+from repro.spans import scope
+
 NEG_INF = -3.0e38       # max-combiner identity (≈ most negative finite f32)
 MASK_VALUE = -1e30      # attention score mask (softmax-safe)
 
@@ -50,11 +52,13 @@ def lane_pack(pool):
     R, D = pool.shape
     P = rows_per_line(D)
     n_lines = -(-R // P)
-    if n_lines * P != R:
-        pool = jnp.pad(pool, ((0, n_lines * P - R), (0, 0)))
-    return pool.reshape(n_lines, P * D)
+    with scope("emb_relayout"):
+        if n_lines * P != R:
+            pool = jnp.pad(pool, ((0, n_lines * P - R), (0, 0)))
+        return pool.reshape(n_lines, P * D)
 
 
 def lane_unpack(lines, R: int, D: int):
     """Inverse of ``lane_pack``: (n_lines, P * D) lines -> (R, D) rows."""
-    return lines.reshape(-1, D)[:R]
+    with scope("emb_relayout"):
+        return lines.reshape(-1, D)[:R]

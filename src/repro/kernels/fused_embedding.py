@@ -91,6 +91,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.common import lane_pack, rows_per_line
+from repro.spans import scope
 
 COMBINERS = ("sum", "mean", "max")
 
@@ -395,6 +396,7 @@ def _pallas_forward(pool, enc_idx, weights, cache, *, T, H, combiner,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="emb_fwd",
     )(*args)
     return out[:, :B, :D].transpose(1, 0, 2)
 
@@ -515,22 +517,23 @@ def sparse_row_grads(pool: jnp.ndarray, indices: jnp.ndarray, g: jnp.ndarray,
     sentinel tail, (B*T*H, D) f32 summed row grads, and the weights
     cotangent (None when unweighted).
     """
-    B, T, H = indices.shape
-    R = pool.shape[0]
-    idx = indices.astype(jnp.int32)
-    if plan.offsets is not None:
-        idx = idx + jnp.asarray(plan.offsets, jnp.int32)[None, :, None]
-    flat_idx = idx.reshape(-1)
-    store_idx = flat_idx if plan.layout is None else \
-        translate_rows(flat_idx, plan.layout)
-    w = None if weights is None else \
-        weights.astype(jnp.float32).reshape(B, T, H)
-    g_rows, dw = _row_cotangents(pool, store_idx, w, g.astype(jnp.float32),
-                                 combiner=plan.combiner, B=B, T=T, H=H)
-    rows, vals = dedupe_rows(store_idx, g_rows.reshape(B * T * H, -1), R)
-    dweights = None if dw is None else dw.reshape(weights.shape).astype(
-        weights.dtype)
-    return rows, vals, dweights
+    with scope("emb_bwd"):
+        B, T, H = indices.shape
+        R = pool.shape[0]
+        idx = indices.astype(jnp.int32)
+        if plan.offsets is not None:
+            idx = idx + jnp.asarray(plan.offsets, jnp.int32)[None, :, None]
+        flat_idx = idx.reshape(-1)
+        store_idx = flat_idx if plan.layout is None else \
+            translate_rows(flat_idx, plan.layout)
+        w = None if weights is None else \
+            weights.astype(jnp.float32).reshape(B, T, H)
+        g_rows, dw = _row_cotangents(pool, store_idx, w, g.astype(jnp.float32),
+                                     combiner=plan.combiner, B=B, T=T, H=H)
+        rows, vals = dedupe_rows(store_idx, g_rows.reshape(B * T * H, -1), R)
+        dweights = None if dw is None else dw.reshape(weights.shape).astype(
+            weights.dtype)
+        return rows, vals, dweights
 
 
 # ---------------------------------------------------------------------------
@@ -538,57 +541,58 @@ def sparse_row_grads(pool: jnp.ndarray, indices: jnp.ndarray, g: jnp.ndarray,
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _fused(pool, flat_idx, weights, meta):
-    combiner, B, T, H, method, block_b, hot, layout, mesh = meta
-    if method in ("pallas", "interpret"):
-        if hot is not None:
-            offsets, table_hot = hot
-            # the cache is gathered from `pool` *inside* the VJP-wrapped
-            # forward, so gradients through cached rows flow to the pool
-            # exactly like uncached ones (row ids are preserved). Flat
-            # layout: the hot prefixes are contiguous, one slice per table.
-            # Padded layout: a table's prefix may straddle a shard boundary,
-            # so gather the statically-translated row ids instead.
-            if layout is None:
-                cache = jnp.concatenate([
-                    jax.lax.slice_in_dim(pool, o, o + k)
-                    for o, k in zip(offsets, table_hot) if k > 0])
+    with scope("emb_fwd"):
+        combiner, B, T, H, method, block_b, hot, layout, mesh = meta
+        if method in ("pallas", "interpret"):
+            if hot is not None:
+                offsets, table_hot = hot
+                # the cache is gathered from `pool` *inside* the VJP-wrapped
+                # forward, so gradients through cached rows flow to the pool
+                # exactly like uncached ones (row ids are preserved). Flat
+                # layout: the hot prefixes are contiguous, one slice per table.
+                # Padded layout: a table's prefix may straddle a shard boundary,
+                # so gather the statically-translated row ids instead.
+                if layout is None:
+                    cache = jnp.concatenate([
+                        jax.lax.slice_in_dim(pool, o, o + k)
+                        for o, k in zip(offsets, table_hot) if k > 0])
+                else:
+                    ids = translate_rows_np(hot_row_ids(offsets, table_hot),
+                                            layout)
+                    cache = jnp.take(pool, jnp.asarray(ids), axis=0)
+                # hot detection speaks FLAT local ids (the placement contract);
+                # encode first, then rebase only the cold (non-negative) entries
+                # into the padded space
+                enc, _ = encode_hot_indices(flat_idx.reshape(B, T, H),
+                                            offsets, table_hot)
+                if layout is not None:
+                    enc = jnp.where(enc < 0, enc,
+                                    translate_rows(jnp.maximum(enc, 0), layout))
             else:
-                ids = translate_rows_np(hot_row_ids(offsets, table_hot),
-                                        layout)
-                cache = jnp.take(pool, jnp.asarray(ids), axis=0)
-            # hot detection speaks FLAT local ids (the placement contract);
-            # encode first, then rebase only the cold (non-negative) entries
-            # into the padded space
-            enc, _ = encode_hot_indices(flat_idx.reshape(B, T, H),
-                                        offsets, table_hot)
-            if layout is not None:
-                enc = jnp.where(enc < 0, enc,
-                                translate_rows(jnp.maximum(enc, 0), layout))
-        else:
-            cache = None
-            enc = flat_idx.reshape(B, T, H)
-            if layout is not None:
-                enc = translate_rows(enc, layout)
-        fwd = functools.partial(_pallas_forward, T=T, H=H,
-                                combiner=combiner, block_b=block_b,
-                                interpret=(method == "interpret"))
-        if mesh is not None:
-            # GSPMD cannot partition a Mosaic kernel: each device runs it on
-            # its own batch shard against the whole pool, which is
-            # all-gathered (replicated) around it
-            mesh, batch_axes = mesh
-            rows = P(batch_axes or None)
-            fwd = jax.shard_map(fwd, mesh=mesh,
-                                in_specs=(P(), rows, rows, P()),
-                                out_specs=rows, check_vma=False)
-        return fwd(pool, enc, weights, cache)
-    # XLA path: under frequency-packed placement the hot prefixes are already
-    # contiguous in the pool and stay hardware-cache-resident; a separate
-    # cache gather would only add traffic, so the plain fused take IS the
-    # cached path here (bit-identical by construction).
-    idx = flat_idx if layout is None else translate_rows(flat_idx, layout)
-    return _xla_forward(pool, idx, weights, B=B, T=T, H=H,
-                        combiner=combiner)
+                cache = None
+                enc = flat_idx.reshape(B, T, H)
+                if layout is not None:
+                    enc = translate_rows(enc, layout)
+            fwd = functools.partial(_pallas_forward, T=T, H=H,
+                                    combiner=combiner, block_b=block_b,
+                                    interpret=(method == "interpret"))
+            if mesh is not None:
+                # GSPMD cannot partition a Mosaic kernel: each device runs it on
+                # its own batch shard against the whole pool, which is
+                # all-gathered (replicated) around it
+                mesh, batch_axes = mesh
+                rows = P(batch_axes or None)
+                fwd = jax.shard_map(fwd, mesh=mesh,
+                                    in_specs=(P(), rows, rows, P()),
+                                    out_specs=rows, check_vma=False)
+            return fwd(pool, enc, weights, cache)
+        # XLA path: under frequency-packed placement the hot prefixes are already
+        # contiguous in the pool and stay hardware-cache-resident; a separate
+        # cache gather would only add traffic, so the plain fused take IS the
+        # cached path here (bit-identical by construction).
+        idx = flat_idx if layout is None else translate_rows(flat_idx, layout)
+        return _xla_forward(pool, idx, weights, B=B, T=T, H=H,
+                            combiner=combiner)
 
 
 def _fused_fwd(pool, flat_idx, weights, meta):
@@ -596,27 +600,28 @@ def _fused_fwd(pool, flat_idx, weights, meta):
 
 
 def _fused_bwd(meta, res, g):
-    combiner, B, T, H, method, block_b, hot, layout, _ = meta
-    pool, flat_idx, weights = res
-    R, D = pool.shape
-    # gradients deposit into the physical store's row space: flat rows when
-    # the pool is unpadded, padded rows under a layout (whose padding slots
-    # are never addressed, so they receive exactly zero)
-    store_idx = flat_idx if layout is None else translate_rows(flat_idx, layout)
-    w = None if weights is None else weights.reshape(B, T, H)
-    g_rows, dw = _row_cotangents(pool, store_idx, w, g.astype(jnp.float32),
-                                 combiner=combiner, B=B, T=T, H=H)
+    with scope("emb_bwd"):
+        combiner, B, T, H, method, block_b, hot, layout, _ = meta
+        pool, flat_idx, weights = res
+        R, D = pool.shape
+        # gradients deposit into the physical store's row space: flat rows when
+        # the pool is unpadded, padded rows under a layout (whose padding slots
+        # are never addressed, so they receive exactly zero)
+        store_idx = flat_idx if layout is None else translate_rows(flat_idx, layout)
+        w = None if weights is None else weights.reshape(B, T, H)
+        g_rows, dw = _row_cotangents(pool, store_idx, w, g.astype(jnp.float32),
+                                     combiner=combiner, B=B, T=T, H=H)
 
-    # Sparse-gradient aggregation through the explicit dedupe+segment step
-    # shared with ``sparse_row_grads``: one scatter of the deduped values
-    # reproduces the old per-index segment_sum (and makes the dense path the
-    # bit-exact oracle for the fused row-wise update, which consumes the
-    # same (rows, vals) pair).
-    rows, vals = dedupe_rows(store_idx, g_rows.reshape(B * T * H, D), R)
-    dpool = jnp.zeros((R, D), jnp.float32).at[rows].add(vals)
-    dweights = None if dw is None else dw.reshape(weights.shape).astype(
-        weights.dtype)
-    return dpool.astype(pool.dtype), None, dweights
+        # Sparse-gradient aggregation through the explicit dedupe+segment step
+        # shared with ``sparse_row_grads``: one scatter of the deduped values
+        # reproduces the old per-index segment_sum (and makes the dense path the
+        # bit-exact oracle for the fused row-wise update, which consumes the
+        # same (rows, vals) pair).
+        rows, vals = dedupe_rows(store_idx, g_rows.reshape(B * T * H, D), R)
+        dpool = jnp.zeros((R, D), jnp.float32).at[rows].add(vals)
+        dweights = None if dw is None else dw.reshape(weights.shape).astype(
+            weights.dtype)
+        return dpool.astype(pool.dtype), None, dweights
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)

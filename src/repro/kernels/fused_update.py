@@ -174,6 +174,7 @@ def _adagrad_pallas(params, acc, rows, vals, *, lr, eps, block, interpret):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name="row_update_adagrad",
     )(rows, vals, p_lines, a_lines)
     return lane_unpack(p_lines, R, D), lane_unpack(a_lines, R, D)
 
@@ -314,6 +315,7 @@ def _adam_pallas(params, m, v, rows, vals, bias, *, lr, b1, b2, eps, wd,
             pltpu.SemaphoreType.DMA((3,)),
         ],
         interpret=interpret,
+        name="row_update_adam",
     )(rows, vals, bias, p_lines, m_lines, v_lines)
     return (lane_unpack(p_lines, R, D), lane_unpack(m_lines, R, D),
             lane_unpack(v_lines, R, D))

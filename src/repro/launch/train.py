@@ -32,6 +32,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation
 
 from repro.configs.base import reduce_config
 from repro.configs.registry import DLRMS, get_arch, get_dlrm
@@ -42,6 +43,7 @@ from repro.data.synthetic import criteo_batch, lm_batch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.sharding.policy import padded_layout_for_ranges, uniform_vocab_ranges
+from repro.spans import PREFIX, span
 from repro.train import optim, replan, trainer
 
 
@@ -194,7 +196,8 @@ def train_dlrm(args) -> dict:
 
     Returns ``{"start_step", "losses" {global step: loss}, "replans",
     "first_step_s"}``; ``first_step_s`` is the wall time to the first
-    step's loss, compile included.
+    step's loss, compile included. Under ``jax.profiler`` each iteration is
+    one ``repro/step`` (see "Profiling a job" in ``docs/ARCHITECTURE.md``).
     """
     from repro.configs.dlrm_models import reduced_dlrm
 
@@ -269,51 +272,61 @@ def train_dlrm(args) -> dict:
     n = 0
     losses = {}                                  # device scalars, read at end
     first_step_s = None
-    for raw in loader:
-        batch = remapper.remap_batch(raw)
-        tracker.observe(batch["sparse"])        # worker-side heartbeat payload
-        state, m = step_fn(state, {k: jnp.asarray(v) for k, v in batch.items()})
-        losses[step0 + n] = m["loss"]
-        n += 1
-        replanned = False
-        if n == 1:
-            m["loss"].block_until_ready()
-            first_step_s = time.time() - t0
-        if n % 20 == 0 or n == 1:
-            print(f"step {n:5d} loss={float(m['loss']):.4f} "
-                  f"imbalance={tracker.imbalance():.3f} "
-                  f"({n*cfg.batch_size/(time.time()-t0):.1f} samples/s)")
-        if args.replan_every and n % args.replan_every == 0:
-            decision = tracker.maybe_replan()
-            if decision is not None:
-                # old-layout snapshot (with its own layout stamp) first, so a
-                # crash mid-replan loses nothing; apply_replan itself then
-                # permutes, re-plans placement, and recompiles
+    while True:
+        with StepTraceAnnotation(PREFIX + "step", step_num=step0 + n):
+            raw = loader.next_batch()
+            if raw is None:
+                break
+            batch = remapper.remap_batch(raw)
+            tracker.observe(batch["sparse"])    # worker-side heartbeat payload
+            with span("h2d"):
+                dev = {k: jnp.asarray(v) for k, v in batch.items()}
+            with span("dispatch"):
+                state, m = step_fn(state, dev)
+            losses[step0 + n] = m["loss"]
+            n += 1
+            replanned = False
+            if n % 20 == 0 or n == 1:
+                with span("progress"):
+                    if n == 1:
+                        m["loss"].block_until_ready()
+                        first_step_s = time.time() - t0
+                    print(f"step {n:5d} loss={float(m['loss']):.4f} "
+                          f"imbalance={tracker.imbalance():.3f} "
+                          f"({n*cfg.batch_size/(time.time()-t0):.1f} samples/s)")
+            if args.replan_every and n % args.replan_every == 0:
+                with span("replan"):
+                    decision = tracker.maybe_replan()
+                    if decision is not None:
+                        # old-layout snapshot (with its own layout stamp) first,
+                        # so a crash mid-replan loses nothing; apply_replan
+                        # itself then permutes, re-plans placement, and
+                        # recompiles
+                        replan.save_with_layout(ckpt, state, int(state["step"]),
+                                                remapper, table_hot, vocab_ranges,
+                                                layout=layout)
+                        res = replan.apply_replan(
+                            state, cfg, opt, decision, remapper=remapper,
+                            opt_name=opt_name, grad_compress=args.grad_compress,
+                            layout=layout, plan=plan)
+                        tracker.mark_applied(decision)
+                        state, step_fn, layout = res.state, res.step_fn, res.layout
+                        plan = res.plan
+                        table_hot = decision.table_hot
+                        vocab_ranges = decision.vocab_ranges
+                        replanned = True
+                        print(f"step {n:5d} RE-PLAN: imbalance "
+                              f"{decision.imbalance_before:.3f} -> "
+                              f"{decision.imbalance_after:.3f}, "
+                              f"cache rows {sum(decision.table_hot)}"
+                              + (f", physical rows/shard {list(layout.shard_sizes)}"
+                                 if layout is not None else ""))
+            if args.ckpt_dir and n % args.ckpt_every == 0 and not replanned:
+                # key by the GLOBAL step so resumed runs sort above their
+                # pre-resume checkpoints (n restarts at 0 on every process)
                 replan.save_with_layout(ckpt, state, int(state["step"]),
                                         remapper, table_hot, vocab_ranges,
                                         layout=layout)
-                res = replan.apply_replan(state, cfg, opt, decision,
-                                          remapper=remapper, opt_name=opt_name,
-                                          grad_compress=args.grad_compress,
-                                          layout=layout, plan=plan)
-                tracker.mark_applied(decision)
-                state, step_fn, layout = res.state, res.step_fn, res.layout
-                plan = res.plan
-                table_hot = decision.table_hot
-                vocab_ranges = decision.vocab_ranges
-                replanned = True
-                print(f"step {n:5d} RE-PLAN: imbalance "
-                      f"{decision.imbalance_before:.3f} -> "
-                      f"{decision.imbalance_after:.3f}, "
-                      f"cache rows {sum(decision.table_hot)}"
-                      + (f", physical rows/shard {list(layout.shard_sizes)}"
-                         if layout is not None else ""))
-        if args.ckpt_dir and n % args.ckpt_every == 0 and not replanned:
-            # key by the GLOBAL step so resumed runs sort above their
-            # pre-resume checkpoints (n restarts at 0 on every process)
-            replan.save_with_layout(ckpt, state, int(state["step"]),
-                                    remapper, table_hot, vocab_ranges,
-                                    layout=layout)
     ok, covered, dup = svc.coverage(0)
     print(f"done: {n} steps, exactly-once={ok} (covered={covered} dup={dup}), "
           f"{tracker.n_replans} re-plan(s), final imbalance "

@@ -28,6 +28,7 @@ from repro.configs.dlrm_models import DLRMConfig
 from repro.kernels import ops
 from repro.models.common import KeyGen, dense_init
 from repro.sharding.policy import constrain, current_policy
+from repro.spans import scope
 
 
 def init_dlrm(cfg: DLRMConfig, key, layout=None) -> Dict[str, Any]:
@@ -125,7 +126,8 @@ def _pool2d(store, layout):
     """Padded (n_ps, max_range, ...) store → the engine's flattened view."""
     if layout is None:
         return store
-    return store.reshape((layout.padded_rows,) + store.shape[2:])
+    with scope("emb_relayout"):
+        return store.reshape((layout.padded_rows,) + store.shape[2:])
 
 
 def _resolve_plan(cfg: DLRMConfig, plan, table_hot, layout):
@@ -193,35 +195,36 @@ def dlrm_forward_from_embeddings(params, batch, embs: Dict[str, Any],
     is the whole backward minus the sparse scatter — the split the fused
     sparse-update step exploits.
     """
-    emb = constrain(embs["deep"], ("batch", None, None))     # (B, m, D)
-    B = emb.shape[0]
-    x0 = jnp.concatenate([batch["dense"], emb.reshape(B, -1)], axis=-1)
+    with scope("dense"):
+        emb = constrain(embs["deep"], ("batch", None, None))     # (B, m, D)
+        B = emb.shape[0]
+        x0 = jnp.concatenate([batch["dense"], emb.reshape(B, -1)], axis=-1)
 
-    if cfg.kind == "wide_deep":
-        deep = _deep_mlp(params, x0, cfg)
-        wide = batch["dense"] @ params["wide_dense"] + jnp.sum(
-            embs["wide"][..., 0], axis=1)
-        return deep + wide
+        if cfg.kind == "wide_deep":
+            deep = _deep_mlp(params, x0, cfg)
+            wide = batch["dense"] @ params["wide_dense"] + jnp.sum(
+                embs["wide"][..., 0], axis=1)
+            return deep + wide
 
-    if cfg.kind == "dcn":
-        x = x0
-        for li in range(cfg.cross_layers):
-            w = params["cross"][f"w{li}"]
-            b = params["cross_b"][f"b{li}"]
-            x = x0 * (x @ w)[:, None] + b + x
-        return _deep_mlp(params, x, cfg)
+        if cfg.kind == "dcn":
+            x = x0
+            for li in range(cfg.cross_layers):
+                w = params["cross"][f"w{li}"]
+                b = params["cross_b"][f"b{li}"]
+                x = x0 * (x @ w)[:, None] + b + x
+            return _deep_mlp(params, x, cfg)
 
-    if cfg.kind == "xdeepfm":
-        Xk = emb                                             # (B, H0=m, D)
-        feats = []
-        for li in range(len(cfg.cin_layers)):
-            inter = jnp.einsum("bhd,bmd->bhmd", Xk, emb)
-            Xk = jnp.einsum("bhmd,hmn->bnd", inter, params["cin"][f"w{li}"])
-            feats.append(jnp.sum(Xk, axis=-1))               # (B, maps)
-        cin_out = jnp.concatenate(feats, axis=-1) @ params["cin"]["w_out"]
-        return _deep_mlp(params, x0, cfg) + cin_out
+        if cfg.kind == "xdeepfm":
+            Xk = emb                                             # (B, H0=m, D)
+            feats = []
+            for li in range(len(cfg.cin_layers)):
+                inter = jnp.einsum("bhd,bmd->bhmd", Xk, emb)
+                Xk = jnp.einsum("bhmd,hmn->bnd", inter, params["cin"][f"w{li}"])
+                feats.append(jnp.sum(Xk, axis=-1))               # (B, maps)
+            cin_out = jnp.concatenate(feats, axis=-1) @ params["cin"]["w_out"]
+            return _deep_mlp(params, x0, cfg) + cin_out
 
-    raise ValueError(cfg.kind)
+        raise ValueError(cfg.kind)
 
 
 def dlrm_forward(params, batch, cfg: DLRMConfig, table_hot=None,

@@ -54,6 +54,7 @@ from repro.sharding.policy import (
     EmbeddingPlan, PaddedLayout, ShardingPolicy, make_dlrm_policy,
     padded_layout_for_ranges, uniform_vocab_ranges,
 )
+from repro.spans import span
 from repro.train import elastic
 from repro.train import trainer as trainer_mod
 from repro.train.optim import Optimizer
@@ -119,9 +120,10 @@ class EmbeddingRemapper:
 
     def remap_batch(self, batch: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """Copy of a criteo-style batch dict with its "sparse" ids remapped."""
-        out = dict(batch)
-        out["sparse"] = self.remap(batch["sparse"])
-        return out
+        with span("remap"):
+            out = dict(batch)
+            out["sparse"] = self.remap(batch["sparse"])
+            return out
 
 
 def permute_train_state(state, total_rows: int, permutation: np.ndarray):
@@ -398,16 +400,18 @@ def save_with_layout(ckpt: FlashCheckpoint, state, step: int,
                  Stamped as ``padded_n_ps`` so a fresh ``--resume`` comes
                  back padded on the same plan.
     """
-    hot = (np.full(len(remapper.table_rows), -1, np.int64)
-           if table_hot is None else np.asarray(table_hot, np.int64))
-    ranges = (np.zeros((0,), np.int64) if vocab_ranges is None
-              else np.asarray(vocab_ranges, np.int64).reshape(-1))
-    if layout is not None:
-        state = unpad_train_state(state, remapper.total_rows, layout)
-    ckpt.save({"state": state, "layout": np.asarray(remapper.map, np.int64),
-               "table_hot": hot, "vocab_ranges": ranges,
-               "padded_n_ps": np.asarray(
-                   0 if layout is None else layout.n_ps, np.int64)}, step)
+    with span("ckpt_stamp"):
+        hot = (np.full(len(remapper.table_rows), -1, np.int64)
+               if table_hot is None else np.asarray(table_hot, np.int64))
+        ranges = (np.zeros((0,), np.int64) if vocab_ranges is None
+                  else np.asarray(vocab_ranges, np.int64).reshape(-1))
+        if layout is not None:
+            state = unpad_train_state(state, remapper.total_rows, layout)
+        blob = {"state": state, "layout": np.asarray(remapper.map, np.int64),
+                "table_hot": hot, "vocab_ranges": ranges,
+                "padded_n_ps": np.asarray(
+                    0 if layout is None else layout.n_ps, np.int64)}
+    ckpt.save(blob, step)
 
 
 def restore_with_layout(cfg: DLRMConfig, optimizer: Optimizer,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs.dlrm_models import DLRMConfig
 from repro.models.dlrm import dlrm_loss
 from repro.models.registry import ModelAPI
+from repro.spans import scope
 from repro.train import optim as optim_mod
 from repro.train.optim import Optimizer
 
@@ -131,11 +132,13 @@ def make_dlrm_train_step(cfg: DLRMConfig, optimizer: Optimizer,
     def train_step(state, batch):
         loss, grads = jax.value_and_grad(
             lambda p: dlrm_loss(p, batch, cfg, plan=plan))(state["params"])
-        if grad_compress:
-            grads = optim_mod.compress_grads(grads)
-        gnorm = optim_mod.global_norm(grads)
-        updates, opt_state = optimizer.update(grads, state["opt"], state["params"])
-        params = optim_mod.apply_updates(state["params"], updates)
+        with scope("opt_update"):
+            if grad_compress:
+                grads = optim_mod.compress_grads(grads)
+            gnorm = optim_mod.global_norm(grads)
+            updates, opt_state = optimizer.update(grads, state["opt"],
+                                                  state["params"])
+            params = optim_mod.apply_updates(state["params"], updates)
         new_state = {"params": params, "opt": opt_state, "step": state["step"] + 1}
         return new_state, {"loss": loss, "grad_norm": gnorm}
 
@@ -204,32 +207,33 @@ def _make_dlrm_sparse_step(cfg: DLRMConfig, optimizer: Optimizer,
                 pool, batch["sparse"], g_embs[emb_of[k]], plan=plan_of[k])
             grads[k] = optim_mod.SparseRowGrad(rows, vals)
 
-        if grad_compress:
-            grads = optim_mod.compress_grads(grads)
-        gnorm = optim_mod.global_norm(grads)
-        if optimizer.clip_norm is not None:
-            grads, _ = optim_mod.clip_by_global_norm(grads,
-                                                     optimizer.clip_norm)
+        with scope("opt_update"):
+            if grad_compress:
+                grads = optim_mod.compress_grads(grads)
+            gnorm = optim_mod.global_norm(grads)
+            if optimizer.clip_norm is not None:
+                grads, _ = optim_mod.clip_by_global_norm(grads,
+                                                         optimizer.clip_norm)
 
-        dense_state, leaf_state = _split_opt_state(state["opt"], sparse_keys)
-        dense_only = {k: v for k, v in grads.items() if k not in sparse_keys}
-        updates, new_dense_state = optimizer.update(
-            dense_only, dense_state, dense_params)
-        new_params = dict(optim_mod.apply_updates(dense_params, updates))
-        new_opt = dict(new_dense_state)
-        for k in sparse_keys:
-            store = params[k]
-            pool = dlrm_mod._pool2d(store, plan.layout)
-            leaf = {name: (dlrm_mod._pool2d(arr, plan.layout)
-                           if getattr(arr, "shape", None) == store.shape
-                           else arr)
-                    for name, arr in leaf_state[k].items()}
-            new_pool, new_leaf = optimizer.update_rows(
-                grads[k].rows, grads[k].vals, leaf, pool)
-            new_params[k] = new_pool.reshape(store.shape)
-            for name, arr in new_leaf.items():
-                new_opt[name] = dict(new_opt[name])
-                new_opt[name][k] = arr.reshape(leaf_state[k][name].shape)
+            dense_state, leaf_state = _split_opt_state(state["opt"], sparse_keys)
+            dense_only = {k: v for k, v in grads.items() if k not in sparse_keys}
+            updates, new_dense_state = optimizer.update(
+                dense_only, dense_state, dense_params)
+            new_params = dict(optim_mod.apply_updates(dense_params, updates))
+            new_opt = dict(new_dense_state)
+            for k in sparse_keys:
+                store = params[k]
+                pool = dlrm_mod._pool2d(store, plan.layout)
+                leaf = {name: (dlrm_mod._pool2d(arr, plan.layout)
+                               if getattr(arr, "shape", None) == store.shape
+                               else arr)
+                        for name, arr in leaf_state[k].items()}
+                new_pool, new_leaf = optimizer.update_rows(
+                    grads[k].rows, grads[k].vals, leaf, pool)
+                new_params[k] = new_pool.reshape(store.shape)
+                for name, arr in new_leaf.items():
+                    new_opt[name] = dict(new_opt[name])
+                    new_opt[name][k] = arr.reshape(leaf_state[k][name].shape)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         return new_state, {"loss": loss, "grad_norm": gnorm}

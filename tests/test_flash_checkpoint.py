@@ -308,3 +308,11 @@ def test_async_persist_waits(tmp_path):
     ck.drop_memory_tier()
     _, step = ck.restore(_state(0.0))
     assert step == 10
+
+
+def test_last_save_bytes_counts_the_device_leaves():
+    import jax.numpy as jnp
+    ck = FlashCheckpoint(None)
+    ck.save({"w": jnp.ones((8, 4), jnp.float32), "ids": jnp.arange(3, dtype=jnp.int32),
+             "host": np.zeros(100), "step": 7}, 1)
+    assert ck.last_save_bytes == 8 * 4 * 4 + 3 * 4
