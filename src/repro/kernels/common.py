@@ -23,6 +23,7 @@ MASK_VALUE = -1e30      # attention score mask (softmax-safe)
 # lane-packed row stores
 # ---------------------------------------------------------------------------
 LANES = 128             # TPU vector lanes: HBM/VMEM tiles are (8, 128) words
+TILE_ROWS = 8 * LANES   # rows of one (8, 128) f32 tile of a D=1 store's lines
 
 
 def rows_per_line(D: int) -> int:

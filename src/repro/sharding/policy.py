@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.kernels.common import TILE_ROWS
 
 # logical axis vocabulary ----------------------------------------------------
 #   batch     activation batch dim
@@ -414,7 +415,8 @@ class PaddedLayout:
     materializes uniform striping — a balanced ``vocab_ranges`` plan riding
     on the policy stays advisory. This layout makes the plan physical:
     shard ``p`` owns exactly ``ranges[p]``'s rows, stored at
-    ``padded[p, 0:size_p]`` and tail-padded with zero rows to ``max_range``.
+    ``padded[p, 0:size_p]`` and tail-padded with zero rows to ``max_range``,
+    a whole number of TPU tiles of rows.
     A ``NamedSharding`` of ``P("model", None, None)`` over the leading axis
     then places *exactly* the balanced plan on the mesh — physically-unequal
     PS shards via an equal split of the padded leading axis.
@@ -440,8 +442,16 @@ class PaddedLayout:
 
     @property
     def max_range(self) -> int:
-        """Rows per physical shard (the largest range, floor 1)."""
-        return max(1, max(e - s for s, e in self.ranges))
+        """Rows per physical shard: the largest range rounded up to whole
+        ``TILE_ROWS`` (floor one tile).
+
+        On a TPU the pool's row axis is the lane-tiled axis, so a shard
+        that is not a whole number of tiles turns every reshape of the pool
+        (the ``(R, D)`` view, its lane-packed lines, the gradient's way
+        back) into an element loop over the whole pool.
+        """
+        largest = max(e - s for s, e in self.ranges)
+        return max(1, -(-largest // TILE_ROWS)) * TILE_ROWS
 
     @property
     def total_rows(self) -> int:

@@ -1,8 +1,9 @@
 """Physically-unequal PS shards: the padded (n_ps, max_range, D) pooled layout.
 
 Covers the acceptance contract of the padded placement path:
-  * the layout planner's row translation is a bijection on real rows, empty
-    shards stay fully padded, and n_ps=1 degenerates to the flat pool.
+  * the layout planner's row translation is a bijection on real rows, every
+    shard is a whole number of TPU tiles of rows, empty shards stay fully
+    padded, and n_ps=1 degenerates to the flat rows at the head of one shard.
   * fused-engine forward AND backward are bit-exact vs the flat reference on
     every impl/combiner, with and without the hot-row cache; padding slots
     receive exactly zero gradient.
@@ -12,7 +13,8 @@ Covers the acceptance contract of the padded placement path:
     from the new balanced ranges) with bit-exact forward loss, matching the
     flat job's replan to the ulp.
   * layout-stamped checkpoints store the canonical flat order: they
-    round-trip flat <-> padded and resume onto a different n_ps.
+    round-trip flat <-> padded, resume onto a different n_ps, and a blob
+    saved before shards were tile-aligned restores onto the aligned layout.
 """
 import dataclasses
 
@@ -25,10 +27,12 @@ from repro.configs.dlrm_models import WIDE_DEEP, reduced_dlrm
 from repro.core.flash_checkpoint import FlashCheckpoint
 from repro.core.sharding_service import HotTableTracker
 from repro.data.synthetic import criteo_batch
+from repro.kernels.common import TILE_ROWS
 from repro.kernels.fused_embedding import (fused_embedding_bag, table_offsets,
                                            translate_rows, translate_rows_np)
 from repro.models.dlrm import dlrm_loss
-from repro.sharding.policy import (EmbeddingPlan, balanced_vocab_ranges,
+from repro.sharding.policy import (EmbeddingPlan, PaddedLayout,
+                                   balanced_vocab_ranges,
                                    padded_layout_for_ranges,
                                    uniform_vocab_ranges)
 from repro.train import elastic, optim, replan, trainer
@@ -56,8 +60,8 @@ def _jb(b):
 # ------------------------------------------------------------- layout planner
 def test_planner_geometry_and_translation_bijection():
     lay = padded_layout_for_ranges([(0, 100), (100, 101), (101, 224)])
-    assert (lay.n_ps, lay.max_range, lay.total_rows) == (3, 123, 224)
-    assert lay.padded_rows == 3 * 123
+    assert (lay.n_ps, lay.max_range, lay.total_rows) == (3, 1024, 224)
+    assert lay.padded_rows == 3 * 1024
     assert lay.shard_sizes == (100, 1, 123)
     tr = lay.row_translation()
     assert len(np.unique(tr)) == lay.total_rows          # injective
@@ -97,12 +101,48 @@ def test_empty_shard_is_fully_padded_tail():
 
 
 def test_n_ps_1_degenerate_layout_is_flat_plus_leading_axis():
+    """One shard: the flat rows at the head of it, zero rows after."""
     lay = padded_layout_for_ranges(uniform_vocab_ranges(224, 1))
-    assert (lay.n_ps, lay.max_range, lay.padded_rows) == (1, 224, 224)
+    assert (lay.n_ps, lay.max_range, lay.padded_rows) == (1, 1024, 1024)
     np.testing.assert_array_equal(lay.row_translation(), np.arange(224))
-    flat = jnp.arange(224.0)[:, None]
-    np.testing.assert_array_equal(np.asarray(lay.pad_rows(flat))[0],
+    flat = jnp.arange(1.0, 225.0)[:, None]
+    padded = np.asarray(lay.pad_rows(flat))[0]
+    np.testing.assert_array_equal(padded[:224], np.asarray(flat))
+    np.testing.assert_array_equal(padded[224:], 0.0)
+
+
+@pytest.mark.parametrize("ranges", [
+    [(0, 100), (100, 101), (101, 224)],                  # tiny
+    [(0, 6), (6, 6), (6, 10), (10, 10)],                 # empty shards
+    [(0, 0), (0, 0)],                                    # no rows at all
+    [(0, 3000)],                                         # one shard
+    [(0, 2048), (2048, 3072)],                           # already whole tiles
+    [(0, 1025), (1025, 2049), (2049, 3000)],             # just over a tile
+    [(0, 2049), (2049, 2050)],                           # just over two
+])
+def test_shards_are_whole_tiles_and_translation_stays_exact(ranges):
+    lay = padded_layout_for_ranges(ranges)
+    largest = max(1, max(e - s for s, e in ranges))
+    assert lay.max_range % TILE_ROWS == 0
+    assert largest <= lay.max_range < largest + TILE_ROWS
+    assert lay.padded_rows == lay.n_ps * lay.max_range
+    # flat_to_padded / translate_rows: a bijection onto the real slots
+    flat_rows = np.arange(lay.total_rows)
+    padded = lay.flat_to_padded(flat_rows)
+    mask = lay.padding_mask().reshape(-1)
+    np.testing.assert_array_equal(np.sort(padded), np.flatnonzero(mask))
+    np.testing.assert_array_equal(lay.padded_to_flat(padded), flat_rows)
+    np.testing.assert_array_equal(
+        np.asarray(translate_rows(jnp.asarray(flat_rows, jnp.int32), lay)),
+        padded)
+    # pad -> unpad is exact, and the padding slots hold zeros
+    flat = jnp.arange(1.0, 1.0 + 3 * lay.total_rows).reshape(-1, 3)
+    store = lay.pad_rows(flat)
+    assert store.shape == (lay.n_ps, lay.max_range, 3)
+    np.testing.assert_array_equal(np.asarray(lay.unpad_rows(store)),
                                   np.asarray(flat))
+    np.testing.assert_array_equal(
+        np.asarray(store).reshape(-1, 3)[~mask], 0.0)
 
 
 def test_traced_translation_matches_host_translation():
@@ -328,3 +368,43 @@ def test_elastic_resume_onto_different_n_ps():
         CFG, opt, "adagrad", ckpt, None, from_layout=lay4, layout=None)
     assert s3["params"]["tables"].shape[0] == R
     assert float(dlrm_loss(s3["params"], b, CFG)) == want
+
+
+class _UnalignedLayout(PaddedLayout):
+    """The placement before shards were tile-aligned: the largest range."""
+
+    @property
+    def max_range(self) -> int:
+        return max(1, max(e - s for s, e in self.ranges))
+
+
+def test_checkpoint_saved_unaligned_restores_onto_aligned_layout():
+    """A blob saved by a job on the unaligned layout stores flat rows and
+    its ranges: it restores onto the tile-aligned layout of those ranges
+    with the real rows unchanged and zero rows after them."""
+    opt = optim.adagrad(0.05)
+    R = CFG.total_embedding_rows
+    decision = _drifted_decision()
+    old = _UnalignedLayout(tuple(decision.vocab_ranges))
+    assert old.max_range % TILE_ROWS                     # really unaligned
+    s_flat = trainer.make_dlrm_train_state(CFG, opt, jax.random.PRNGKey(6))
+    s_old = replan.pad_train_state(s_flat, R, old)
+    ckpt = FlashCheckpoint()
+    replan.save_with_layout(ckpt, s_old, 9,
+                            replan.EmbeddingRemapper(CFG.table_rows),
+                            decision.table_hot, decision.vocab_ranges,
+                            layout=old)
+    state, step, _, hot, ranges, lay = replan.restore_with_layout(
+        CFG, opt, ckpt)
+    assert step == 9 and hot == decision.table_hot
+    assert lay == padded_layout_for_ranges(ranges) and lay.ranges == old.ranges
+    assert lay.max_range % TILE_ROWS == 0 and lay.max_range > old.max_range
+    assert state["params"]["tables"].shape[:2] == (N_PS, lay.max_range)
+    # real rows: zero difference from the unaligned job's; padding zero
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)),
+        replan.unpad_train_state(state, R, lay),
+        replan.unpad_train_state(s_old, R, old))
+    pad = ~lay.padding_mask()
+    for leaf in (state["params"]["tables"], state["opt"]["acc"]["tables"]):
+        np.testing.assert_array_equal(np.asarray(leaf)[pad], 0.0)

@@ -12,13 +12,15 @@ the installed TPU compiler compiles, at the published ``wide_deep`` widths
   and batch on "data", as (data=1, model=4) and (data=2, model=2).
 
 Each compiled program must contain the kernel (``tpu_custom_call``) and fit
-one chip's 16 GB of HBM. Nothing runs: these are compiles, not chip runs.
+one chip's 16 GB of HBM; the one-chip step must carry no pool through a
+``while`` loop (tile-aligned PS shards keep every pool reshape a copy). Nothing runs: these are compiles, not chip runs.
 The topology is described inside a module fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU compiler.
 """
 import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,9 +128,26 @@ def test_full_width_train_step_compiles(one_chip, tpu_backend, sparse_update):
              "label": _spec(one_chip, (B,))}
     compiled = _compile_on_chip(
         trainer.make_dlrm_train_step(CFG, opt, plan=plan), state, batch)
+    text = compiled.as_text()
     # forward kernels (deep + wide), plus the row updates on the sparse path
-    assert compiled.as_text().count("tpu_custom_call") >= (4 if sparse_update
-                                                          else 2)
+    assert text.count("tpu_custom_call") >= (4 if sparse_update else 2)
+    # tile-aligned shards: no reshape of a pool lowers to an element loop
+    loops = _pool_loops(text, layout.max_range)
+    assert not loops, loops
+
+
+def _pool_loops(text, rows):
+    """The ``while`` loops of an HLO text that carry an f32 array of at
+    least ``rows`` elements, as ``(instruction, shape)`` pairs."""
+    found = []
+    for line in text.splitlines():
+        if " while(" not in line or " = " not in line:
+            continue
+        name, rest = line.split(" = ", 1)
+        for dims in re.findall(r"f32\[([\d,]*)\]", rest.split(" while(")[0]):
+            if math.prod(int(d) for d in dims.split(",") if d) >= rows:
+                found.append((name.strip(), f"f32[{dims}]"))
+    return found
 
 
 def _nbytes(x, shard_shape=None):
